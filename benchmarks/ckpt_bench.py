@@ -46,6 +46,7 @@ def _cases():
 def _setup(c):
     from repro.configs.registry import smoke_config
     from repro.data import make_synthetic_loader
+    from repro.launch.mesh import make_mesh
     from repro.models import build_model
     from repro.optim import AdamW
     from repro.parallel import plan as plan_lib
@@ -57,7 +58,7 @@ def _setup(c):
     model = build_model(cfg)
     opt = AdamW(lr=1e-3, param_dtype="float32")
     plan = ParallelPlan(mode="gspmd")
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     params = model.init(jax.random.PRNGKey(0))
     state = plan_lib.init_state(plan, opt, params, mesh)
     step_fn = plan_lib.make_train_step(plan, model, opt, mesh,

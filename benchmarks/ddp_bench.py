@@ -46,6 +46,7 @@ def _child():
     from repro.models import build_model
     from repro.optim import AdamW
     from repro.parallel.plan import ParallelPlan
+    from repro.launch.mesh import make_mesh
 
     smoke = os.environ.get("REPRO_BENCH_SMOKE") == "1"
     n_layers, steps, bucket_kib = (2, 2, 64) if smoke else (4, 8, 256)
@@ -55,7 +56,7 @@ def _child():
     opt = AdamW(lr=1e-3, param_dtype="float32")
     params = model.init(jax.random.PRNGKey(0))
     state = opt.init(params)
-    mesh = jax.make_mesh((2, 4), ("pod", "data"))
+    mesh = make_mesh((2, 4), ("pod", "data"))
     batch = {k: jnp.asarray(v)
              for k, v in batch_for_model(cfg, "train", 0, 8, 32).items()}
     loss_fn = lambda p, b: model.loss(p, b)  # noqa: E731
@@ -105,6 +106,7 @@ def _child():
 def run():
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"    # fake devices; never the accelerator
     src = os.path.join(os.path.dirname(__file__), "..", "src")
     env["PYTHONPATH"] = os.pathsep.join(
         p for p in (src, env.get("PYTHONPATH", "")) if p)

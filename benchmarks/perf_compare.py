@@ -17,7 +17,7 @@ def load(path):
 
 
 def terms(rec):
-    chip = hw.V5E
+    chip = hw.chip_spec(rec.get("device_kind"))
     h = rec["hlo"]
     return {
         "compute_s": h["flops"] / chip.peak_bf16_flops,

@@ -47,7 +47,7 @@ def model_flops_per_chip(arch: str, shape_name: str, n_chips: int) -> float:
 
 
 def analyze_cell(rec: dict) -> dict:
-    chip = hw.V5E
+    chip = hw.chip_spec(rec.get("device_kind"))
     h = rec["hlo"]
     compute_s = h["flops"] / chip.peak_bf16_flops
     memory_s = h["bytes"] / chip.hbm_bw

@@ -15,6 +15,7 @@
 import os
 
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
+os.environ["JAX_PLATFORMS"] = "cpu"     # 8 fake CPU devices, never a chip
 
 import dataclasses  # noqa: E402
 import json  # noqa: E402
@@ -22,12 +23,12 @@ import tempfile  # noqa: E402
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
-import numpy as np  # noqa: E402
 
 from repro.ckpt import fs3_backend  # noqa: E402
 from repro.configs.registry import smoke_config  # noqa: E402
 from repro.data.synthetic import batch_for_model  # noqa: E402
 from repro.elastic import ElasticCheckpointer  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.models import build_model  # noqa: E402
 from repro.optim import AdamW  # noqa: E402
 from repro.parallel.plan import (ParallelPlan, init_state,  # noqa: E402
@@ -49,9 +50,9 @@ def main():
     # two worlds: healthy = pp over all 8 devices; degraded = ddp+zero1
     # over the 4 survivors.  Both are just ParallelPlans — the elastic
     # layer reshards the checkpoint between them.
-    mesh_pp = jax.make_mesh((2, 2, 2), ("pipe", "pod", "data"))
-    mesh_dp = jax.sharding.Mesh(
-        np.array(jax.devices()[:4]).reshape(1, 4), ("pod", "data"))
+    mesh_pp = make_mesh((2, 2, 2), ("pipe", "pod", "data"))
+    mesh_dp = make_mesh((1, 4), ("pod", "data"),
+                        devices=jax.devices()[:4])
     plan_pp = ParallelPlan(mode="pp", pp_microbatches=2)
     plan_dp = ParallelPlan(mode="ddp", zero1=True, overlap=False)
 

@@ -9,6 +9,7 @@ import jax.numpy as jnp
 
 from repro.configs.registry import smoke_config
 from repro.data.synthetic import batch_for_model
+from repro.launch.mesh import make_mesh
 from repro.models import build_model
 from repro.optim import AdamW, warmup_cosine
 from repro.parallel.plan import ParallelPlan, init_state, make_train_step
@@ -26,7 +27,7 @@ def main():
     #    HFReduce or pipelined paths (launch/train.py --parallel).
     opt = AdamW(lr=warmup_cosine(3e-3, 2, 20), param_dtype="float32")
     params = model.init(jax.random.PRNGKey(0))
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     plan = ParallelPlan(mode="gspmd", tp=1, fsdp=False,
                         batch_axes=("data",))
     state = init_state(plan, opt, params, mesh)

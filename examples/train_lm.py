@@ -19,6 +19,7 @@ from repro.ckpt.manager import _FS3Backend
 from repro.configs.registry import get_arch
 from repro.data import make_synthetic_loader
 from repro.fs3 import FS3Client, FS3Cluster
+from repro.launch.mesh import make_mesh
 from repro.models import build_model
 from repro.optim import AdamW, warmup_cosine
 from repro.parallel.plan import ParallelPlan, init_state, make_train_step
@@ -45,7 +46,7 @@ def main():
     opt = AdamW(lr=warmup_cosine(3e-4, 20, args.steps),
                 param_dtype="float32")
     params = model.init(jax.random.PRNGKey(0))
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     plan = ParallelPlan(mode="gspmd", tp=1, fsdp=False,
                         batch_axes=("data",))
     state = init_state(plan, opt, params, mesh)

@@ -100,7 +100,17 @@ def flatten_tree(tree, wire_dtype=None) -> jax.Array:
 
 
 def unflatten_leaves(flat: jax.Array, shapes, dtypes, sizes) -> list:
-    """Split a flat concat back into leaves (restoring leaf dtypes)."""
+    """Split a flat concat back into leaves (restoring leaf dtypes).
+
+    A sub-32-bit flat is split from an fp32 copy (exact round trip):
+    slicing a packed 1-D array at leaf offsets off its tile (2048
+    elements in bf16; fp32 tiles hold 1024) makes the TPU compile time
+    grow with the element count.  gpt2-medium's ZeRO-1 step for a
+    v5e:2x2 host took 1069 s to compile without this, 48 s with it.
+    The barrier keeps XLA from fusing the cast back into the slices.
+    """
+    if flat.dtype.itemsize < 4:
+        flat = jax.lax.optimization_barrier(flat.astype(jnp.float32))
     out, off = [], 0
     for shape, dtype, size in zip(shapes, dtypes, sizes):
         out.append(flat[off:off + size].reshape(shape).astype(dtype))

@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.core.axis import axis_size
 
 BLOCK = 256
 
@@ -60,7 +59,7 @@ def fp8_psum(x, axis_name):
     supported on every backend); dequantize + sum in fp32 locally;
     requantize; all_gather; dequantize.  Wire bytes per rank: 2 * |x| / 4.
     """
-    P = axis_size(axis_name)
+    P = lax.axis_size(axis_name)
     if P == 1:
         return x
     shape, dtype = x.shape, x.dtype
@@ -91,7 +90,7 @@ def int8_psum(x, axis_name, block=BLOCK):
     all_gather the reduced chunks.  Wire bytes per rank: 2 * |x| / 4 (int8)
     + scales — vs 2 * |x| fp32 for a flat psum.
     """
-    P = axis_size(axis_name)
+    P = lax.axis_size(axis_name)
     if P == 1:
         return x
     shape, dtype = x.shape, x.dtype

@@ -166,7 +166,6 @@ def make_ddp_train_step(loss_fn: Callable, optimizer, mesh,
     masters — but leaves the caller's input state unusable afterwards.
     Returns ``(step, BucketPlan)``.
     """
-    from jax.experimental.shard_map import shard_map
 
     donate_kw = dict(donate_argnums=(0,)) if donate else {}
 
@@ -185,10 +184,10 @@ def make_ddp_train_step(loss_fn: Callable, optimizer, mesh,
         local_step, state_spec = _make_zero1_local_step(
             loss_fn, optimizer, mesh, plan, params_template,
             axes_in_mesh, strong, weak, n_shards)
-        step = shard_map(local_step, mesh=mesh,
-                         in_specs=(state_spec, batch_spec),
-                         out_specs=(state_spec, P()),
-                         check_rep=False)
+        step = jax.shard_map(local_step, mesh=mesh,
+                             in_specs=(state_spec, batch_spec),
+                             out_specs=(state_spec, P()),
+                             check_vma=False)
         return jax.jit(step, **donate_kw), bucket_plan
 
     sync = make_ddp_grad_sync(
@@ -215,11 +214,11 @@ def make_ddp_train_step(loss_fn: Callable, optimizer, mesh,
         return new_state, {"loss": loss, **{k: jax.lax.pmean(v, axes_in_mesh)
                                             for k, v in metrics.items()}}
 
-    step = shard_map(
+    step = jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(P(), batch_spec),
         out_specs=(P(), P()),
-        check_rep=False)
+        check_vma=False)
     return jax.jit(step, **donate_kw), bucket_plan
 
 

@@ -28,8 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.core.axis import axis_size
-
 
 def _pad_to(x, mult):
     n = x.shape[0]
@@ -55,7 +53,7 @@ def hfreduce(x, *, strong_axis="data", weak_axis="pod",
     therefore the absolute error — 1/n_shards smaller (DESIGN.md §3).
     """
     weak_psum = weak_psum or (lambda v, ax: lax.psum(v, ax))
-    strong = axis_size(strong_axis)
+    strong = lax.axis_size(strong_axis)
     shape = x.shape
     flat = x.reshape(-1)
     flat, pad = _pad_to(flat, strong)

@@ -22,8 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.core.axis import axis_size
-
 
 # ------------------------- schedule construction ---------------------------
 
@@ -78,7 +76,7 @@ def _masks(pairs, n):
 
 
 def _tree_allreduce_one(x, axis_name, shift):
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     if n == 1:
         return x
     reduce_rounds, bcast_rounds = tree_schedule(n, shift)
@@ -97,7 +95,7 @@ def _tree_allreduce_one(x, axis_name, shift):
 
 def tree_allreduce(x, axis_name="pod"):
     """Double binary tree: two complementary trees, half the data each."""
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     if n == 1:
         return x
     shape = x.shape
@@ -116,7 +114,7 @@ def tree_allreduce(x, axis_name="pod"):
 
 def ring_allreduce(x, axis_name="data"):
     """Reference ring (reduce-scatter + all-gather), the 'NCCL' analogue."""
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     if n == 1:
         return x
     shape = x.shape

@@ -53,6 +53,25 @@ V5E = ChipSpec(
     dci_bw_per_chip=TPU_DCI_BW_PER_CHIP,
 )
 
+# Peak tables keyed by ``jax.Device.device_kind``.  Source of the v5e
+# figures: Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16,
+# 16 GB HBM at 819 GB/s per chip).
+CHIPS = {"TPU v5 lite": V5E}
+
+# The chip the dry-run models when it lowers on fake CPU devices.
+DRYRUN_DEVICE_KIND = "TPU v5 lite"
+
+
+def chip_spec(device_kind: str) -> ChipSpec:
+    """Peaks of ``device_kind``; a kind without a table is an error, never
+    a silent default."""
+    try:
+        return CHIPS[device_kind]
+    except KeyError:
+        raise KeyError(f"no peak table for device kind {device_kind!r}; "
+                       f"known: {sorted(CHIPS)}") from None
+
+
 # ---------------------------------------------------------------------------
 # 2. Fire-Flyer 2 universe (paper constants, used to reproduce tables/figs)
 # ---------------------------------------------------------------------------

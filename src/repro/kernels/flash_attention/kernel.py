@@ -99,7 +99,7 @@ def _attn_fwd_kernel(q_ref, k_ref, v_ref, o_ref, *rest, causal: bool,
         l_safe = jnp.maximum(l_sc[...], 1e-30)
         o_ref[0, 0] = (acc_sc[...] / l_safe[:, None]).astype(o_ref.dtype)
         if save_lse:
-            lse_ref[0, 0] = m_sc[...] + jnp.log(l_safe)
+            lse_ref[0, 0] = (m_sc[...] + jnp.log(l_safe))[:, None]
 
 
 def flash_attention_fwd(q, k, v, *, causal=True, bq=128, bk=128,
@@ -111,6 +111,8 @@ def flash_attention_fwd(q, k, v, *, causal=True, bq=128, bk=128,
     skv - sq (end-aligned, the decode/prefill-continuation convention).
     ``kv_len``: number of valid keys (< skv masks padded key positions).
     ``save_residuals``: also return the per-row logsumexp (b, h, sq) fp32.
+    The kernel writes it as (b, h, sq, 1) so each (bq, 1) block keeps a
+    full trailing dim, the tiling the TPU compiler accepts.
     """
     b, h, sq, d = q.shape
     kvh, skv = k.shape[1], k.shape[2]
@@ -131,9 +133,9 @@ def flash_attention_fwd(q, k, v, *, causal=True, bq=128, bk=128,
     out_specs = [pl.BlockSpec((1, 1, bq, d),
                               lambda b_, h_, i, j: (b_, h_, i, 0))]
     if save_residuals:
-        out_shape.append(jax.ShapeDtypeStruct((b, h, sq), jnp.float32))
-        out_specs.append(pl.BlockSpec((1, 1, bq),
-                                      lambda b_, h_, i, j: (b_, h_, i)))
+        out_shape.append(jax.ShapeDtypeStruct((b, h, sq, 1), jnp.float32))
+        out_specs.append(pl.BlockSpec((1, 1, bq, 1),
+                                      lambda b_, h_, i, j: (b_, h_, i, 0)))
     out = pl.pallas_call(
         kernel,
         grid=(b, h, nq, nk),
@@ -153,7 +155,9 @@ def flash_attention_fwd(q, k, v, *, causal=True, bq=128, bk=128,
         ],
         interpret=interpret,
     )(q, k, v)
-    return tuple(out) if save_residuals else out[0]
+    if save_residuals:
+        return out[0], out[1][..., 0]
+    return out[0]
 
 
 # ------------------------------ backward: dK/dV -----------------------------
@@ -178,20 +182,20 @@ def _attn_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     for g in range(group):
         q = q_ref[0, g].astype(jnp.float32)        # (bq, d)
         do = do_ref[0, g].astype(jnp.float32)      # (bq, d)
-        lse = lse_ref[0, g]                        # (bq,)
-        delta = delta_ref[0, g]                    # (bq,)
+        lse = lse_ref[0, g]                        # (bq, 1)
+        delta = delta_ref[0, g]                    # (bq, 1)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         s = _mask_scores(s, causal=causal, kv_len=kv_len, q_offset=q_offset,
                          qi=qi, ki=ji, bq=bq, bk=bk)
-        p = jnp.exp(s - lse[:, None])              # (bq, bk), masked -> 0
+        p = jnp.exp(s - lse)                       # (bq, bk), masked -> 0
         dv_sc[...] += jax.lax.dot_general(
             p, do, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)    # p^T @ do  (bk, d)
         dp = jax.lax.dot_general(
             do, v, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)    # do @ v^T  (bq, bk)
-        ds = p * (dp - delta[:, None]) * scale
+        ds = p * (dp - delta) * scale
         dk_sc[...] += jax.lax.dot_general(
             ds, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)    # ds^T @ q  (bk, d)
@@ -209,6 +213,7 @@ def flash_attention_bwd_dkv(q, k, v, do, lse, delta, *, causal=True,
 
     ``delta`` = rowsum(o * do), shape (b, h, sq) fp32.
     """
+    lse, delta = lse[..., None], delta[..., None]
     b, h, sq, d = q.shape
     kvh, skv = k.shape[1], k.shape[2]
     group = h // kvh
@@ -229,8 +234,10 @@ def flash_attention_bwd_dkv(q, k, v, do, lse, delta, *, causal=True,
             pl.BlockSpec((1, 1, bk, d), lambda b_, g_, j, i: (b_, g_, j, 0)),
             pl.BlockSpec((1, group, bq, d),
                          lambda b_, g_, j, i: (b_, g_, i, 0)),
-            pl.BlockSpec((1, group, bq), lambda b_, g_, j, i: (b_, g_, i)),
-            pl.BlockSpec((1, group, bq), lambda b_, g_, j, i: (b_, g_, i)),
+            pl.BlockSpec((1, group, bq, 1),
+                         lambda b_, g_, j, i: (b_, g_, i, 0)),
+            pl.BlockSpec((1, group, bq, 1),
+                         lambda b_, g_, j, i: (b_, g_, i, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, bk, d), lambda b_, g_, j, i: (b_, g_, j, 0)),
@@ -266,17 +273,17 @@ def _attn_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     k = k_ref[0, 0].astype(jnp.float32)            # (bk, d)
     v = v_ref[0, 0].astype(jnp.float32)            # (bk, d)
     do = do_ref[0, 0].astype(jnp.float32)          # (bq, d)
-    lse = lse_ref[0, 0]                            # (bq,)
-    delta = delta_ref[0, 0]                        # (bq,)
+    lse = lse_ref[0, 0]                            # (bq, 1)
+    delta = delta_ref[0, 0]                        # (bq, 1)
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
     s = _mask_scores(s, causal=causal, kv_len=kv_len, q_offset=q_offset,
                      qi=qi, ki=ki, bq=bq, bk=bk)
-    p = jnp.exp(s - lse[:, None])
+    p = jnp.exp(s - lse)
     dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
-    ds = p * (dp - delta[:, None]) * scale
+    ds = p * (dp - delta) * scale
     dq_sc[...] += jax.lax.dot_general(
         ds, k, (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)        # ds @ k  (bq, d)
@@ -290,6 +297,7 @@ def flash_attention_bwd_dq(q, k, v, do, lse, delta, *, causal=True,
                            bq=128, bk=128, q_offset=0, kv_len=None,
                            interpret=False):
     """dQ ((b, h, sq, d) fp32) from saved lse + delta."""
+    lse, delta = lse[..., None], delta[..., None]
     b, h, sq, d = q.shape
     kvh, skv = k.shape[1], k.shape[2]
     group = h // kvh
@@ -310,8 +318,8 @@ def flash_attention_bwd_dq(q, k, v, do, lse, delta, *, causal=True,
             pl.BlockSpec((1, 1, bk, d),
                          lambda b_, h_, i, j: (b_, h_ // group, j, 0)),
             pl.BlockSpec((1, 1, bq, d), lambda b_, h_, i, j: (b_, h_, i, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b_, h_, i, j: (b_, h_, i)),
-            pl.BlockSpec((1, 1, bq), lambda b_, h_, i, j: (b_, h_, i)),
+            pl.BlockSpec((1, 1, bq, 1), lambda b_, h_, i, j: (b_, h_, i, 0)),
+            pl.BlockSpec((1, 1, bq, 1), lambda b_, h_, i, j: (b_, h_, i, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, bq, d),
                                lambda b_, h_, i, j: (b_, h_, i, 0)),

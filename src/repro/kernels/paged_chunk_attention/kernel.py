@@ -3,9 +3,10 @@
 One-pass online-softmax attention of a **chunk of T >= 1 query tokens**
 per sequence against a block-paged KV pool — the superset of the old
 flash-decode kernel (T = 1) that also covers prefill chunks and
-speculative verify windows.  The grid walks (seq, kv_head, kv_block)
+speculative verify windows.  The grid walks (seq, kv_block)
 with the kv_block axis innermost and sequential, so the (m, l, acc)
-running stats live in VMEM scratch across a sequence's blocks.
+running stats live in VMEM scratch across a sequence's blocks.  Each
+step moves one physical block with all of its kv heads.
 
 The block-table gather costs nothing extra in HBM traffic: the table
 and per-sequence max query positions ride in as scalar-prefetch
@@ -55,9 +56,9 @@ NEG_INF = -1e30
 
 def _chunk_kernel(bt_ref, maxpos_ref, q_ref, qpos_ref, k_ref, v_ref,
                   ks_ref, vs_ref, o_ref, m_sc, l_sc, acc_sc,
-                  *, bs: int, scale: float, nb: int):
+                  *, bs: int, scale: float, nb: int, kvh: int):
     si = pl.program_id(0)          # sequence (batch slot)
-    ji = pl.program_id(2)          # kv block (innermost, sequential)
+    ji = pl.program_id(1)          # kv block (innermost, sequential)
 
     @pl.when(ji == 0)
     def _init():
@@ -69,37 +70,43 @@ def _chunk_kernel(bt_ref, maxpos_ref, q_ref, qpos_ref, k_ref, v_ref,
     # (T=1) touches exactly ceil(len/bs) blocks of the padded table
     @pl.when(ji * bs <= maxpos_ref[si])
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)                    # (R, d)
         qpos = qpos_ref[0]                                     # (R, 1)
-        k = k_ref[0, :, 0, :].astype(jnp.float32) * ks_ref[0]  # (bs, d)
-        v = v_ref[0, :, 0, :].astype(jnp.float32) * vs_ref[0]
-
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        R = s.shape[0]
+        R = qpos.shape[0]
         kpos = ji * bs + jax.lax.broadcasted_iota(jnp.int32, (R, bs), 1)
         valid = (kpos <= qpos) & (qpos >= 0)
-        s = jnp.where(valid, s, NEG_INF)
+        ks = ks_ref[0]                                         # (bs, 1)
+        vs = vs_ref[0]
+        # the whole (bs, kvh, d) block is one DMA; each kv head reads its
+        # (bs, d) slice of it on-chip
+        for h in range(kvh):
+            q = q_ref[0, h].astype(jnp.float32)                # (R, d)
+            k = k_ref[0, :, h, :].astype(jnp.float32) * ks     # (bs, d)
+            v = v_ref[0, :, h, :].astype(jnp.float32) * vs
 
-        m_prev = m_sc[...]
-        l_prev = l_sc[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-        alpha = jnp.exp(m_prev - m_new)
-        # mask the probabilities, not just the logits: an all-masked row
-        # has m_new == NEG_INF and exp(NEG_INF - NEG_INF) == 1, which
-        # would silently accumulate mass; zeroing through `valid` keeps
-        # l == 0 so _finish emits exact zeros for padding rows
-        p = jnp.where(valid, jnp.exp(s - m_new[:, None]), 0.0)
-        l_sc[...] = l_prev * alpha + jnp.sum(p, axis=1)
-        acc_sc[...] = acc_sc[...] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_sc[...] = m_new
+            s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            s = jnp.where(valid, s * scale, NEG_INF)
+
+            m_prev = m_sc[h]                                   # (R, 1)
+            l_prev = l_sc[h]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            # mask the probabilities, not just the logits: an all-masked
+            # row has m_new == NEG_INF and exp(NEG_INF - NEG_INF) == 1,
+            # which would silently accumulate mass; zeroing through
+            # `valid` keeps l == 0 so _finish emits exact zeros for
+            # padding rows
+            p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+            l_sc[h] = l_prev * alpha + jnp.sum(p, axis=1, keepdims=True)
+            acc_sc[h] = acc_sc[h] * alpha + jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            m_sc[h] = m_new
 
     @pl.when(ji == nb - 1)
     def _finish():
         l_safe = jnp.maximum(l_sc[...], 1e-30)
-        o_ref[0, 0] = (acc_sc[...] / l_safe[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_sc[...] / l_safe).astype(o_ref.dtype)
 
 
 def paged_chunk_attention_kernel(q, qpos, k_pool, v_pool, k_scale, v_scale,
@@ -112,36 +119,42 @@ def paged_chunk_attention_kernel(q, qpos, k_pool, v_pool, k_scale, v_scale,
     ops.py for the packing.  ``maxpos[s]`` is the max of sequence s's
     query positions (negative when the whole chunk is padding: every
     block is skipped and the output rows are zeros).
+
+    Each grid step takes one physical block with all ``kvh`` heads: a
+    (bs, kvh, d) block keeps the pool's full trailing dims, which is the
+    tiling the TPU compiler accepts for any kvh (a one-head block would
+    put a size-1 dim second-minor).
     """
     b, kvh, R, d = q.shape
     bs = k_pool.shape[1]
     nbmax = block_tables.shape[1]
     scale = d ** -0.5
 
-    kernel = functools.partial(_chunk_kernel, bs=bs, scale=scale, nb=nbmax)
+    kernel = functools.partial(_chunk_kernel, bs=bs, scale=scale, nb=nbmax,
+                               kvh=kvh)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, kvh, nbmax),
+        grid=(b, nbmax),
         in_specs=[
-            pl.BlockSpec((1, 1, R, d),
-                         lambda s_, h_, j, bt, mp: (s_, h_, 0, 0)),
+            pl.BlockSpec((1, kvh, R, d),
+                         lambda s_, j, bt, mp: (s_, 0, 0, 0)),
             pl.BlockSpec((1, R, 1),
-                         lambda s_, h_, j, bt, mp: (s_, 0, 0)),
-            pl.BlockSpec((1, bs, 1, d),
-                         lambda s_, h_, j, bt, mp: (bt[s_, j], 0, h_, 0)),
-            pl.BlockSpec((1, bs, 1, d),
-                         lambda s_, h_, j, bt, mp: (bt[s_, j], 0, h_, 0)),
+                         lambda s_, j, bt, mp: (s_, 0, 0)),
+            pl.BlockSpec((1, bs, kvh, d),
+                         lambda s_, j, bt, mp: (bt[s_, j], 0, 0, 0)),
+            pl.BlockSpec((1, bs, kvh, d),
+                         lambda s_, j, bt, mp: (bt[s_, j], 0, 0, 0)),
             pl.BlockSpec((1, bs, 1),
-                         lambda s_, h_, j, bt, mp: (bt[s_, j], 0, 0)),
+                         lambda s_, j, bt, mp: (bt[s_, j], 0, 0)),
             pl.BlockSpec((1, bs, 1),
-                         lambda s_, h_, j, bt, mp: (bt[s_, j], 0, 0)),
+                         lambda s_, j, bt, mp: (bt[s_, j], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, R, d),
-                               lambda s_, h_, j, bt, mp: (s_, h_, 0, 0)),
+        out_specs=pl.BlockSpec((1, kvh, R, d),
+                               lambda s_, j, bt, mp: (s_, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((R,), jnp.float32),
-            pltpu.VMEM((R,), jnp.float32),
-            pltpu.VMEM((R, d), jnp.float32),
+            pltpu.VMEM((kvh, R, 1), jnp.float32),
+            pltpu.VMEM((kvh, R, 1), jnp.float32),
+            pltpu.VMEM((kvh, R, d), jnp.float32),
         ],
     )
     return pl.pallas_call(

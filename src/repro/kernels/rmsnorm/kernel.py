@@ -97,12 +97,13 @@ def _rmsnorm_bwd_dw_kernel(x_ref, dy_ref, r_ref, dwp_ref):
     x = x_ref[...].astype(jnp.float32)              # (bn, d)
     dy = dy_ref[...].astype(jnp.float32)            # (bn, d)
     r = r_ref[...]                                  # (bn, 1)
-    dwp_ref[...] = jnp.sum(dy * x * r, axis=0, keepdims=True)
+    dwp_ref[0] = jnp.sum(dy * x * r, axis=0, keepdims=True)
 
 
 def rmsnorm_bwd_dw(x, dy, rstd, *, block_rows=256, interpret=False):
-    """Pass 1: per-row-block partial dw (n_blocks, d) fp32; pass 2 (jnp):
-    sum over blocks."""
+    """Pass 1: per-row-block partial dw (n_blocks, 1, d) fp32; pass 2
+    (jnp): sum over blocks.  Each partial is a (1, d) block whose trailing
+    dims are the array's full trailing dims, as the TPU compiler wants."""
     n, d = x.shape
     bn = min(block_rows, n)
     assert n % bn == 0, (n, bn)
@@ -114,8 +115,8 @@ def rmsnorm_bwd_dw(x, dy, rstd, *, block_rows=256, interpret=False):
             pl.BlockSpec((bn, d), lambda i: (i, 0)),
             pl.BlockSpec((bn, 1), lambda i: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((1, d), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n // bn, d), jnp.float32),
+        out_specs=pl.BlockSpec((1, 1, d), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((n // bn, 1, d), jnp.float32),
         interpret=interpret,
     )(x, dy, rstd)
-    return jnp.sum(partial, axis=0)
+    return jnp.sum(partial, axis=(0, 1))

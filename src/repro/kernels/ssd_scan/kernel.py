@@ -5,9 +5,18 @@ recurrence is reformulated as chunked matmuls (MXU work) with the carried
 state held in VMEM scratch across the sequential chunk axis of the grid —
 HBM sees each chunk exactly once.
 
-Forward grid: (batch, n_chunks) with chunks innermost (sequential on TPU).
-Per-chunk working set at (c=256, h<=64, p=64, n<=128):
-  x (c,h,p) + decay L (h,c,c) fp32 ~ 16-20 MB — fits v5e VMEM.
+Both grids are (batch, n_chunks, head) and run sequentially over chunks
+and heads.  The wrappers lay x/dy/y/dx out head-major ((b, h, l, p)) and
+the within-chunk cumulative log-decays as per-head columns and rows, so
+every in-kernel operand is a 2-D tile whose trailing dims the TPU
+compiler accepts, and every contraction is a plain 2-D matmul.  The
+cumulative sums themselves (forward, and the reverse one that turns
+d(cum) into d(a)) run in jnp around the kernels: Pallas TPU does not
+lower ``jnp.cumsum``.  Each head's carried state (p, n)
+lives in a (h, p, n) VMEM scratch indexed by the head grid index; the
+(c, c) ``C @ B^T`` scores are computed once per chunk (at head 0) and
+reused by the chunk's other heads.  Per-step working set at (c=256,
+p=64, n<=128): a handful of (c, c) fp32 tiles, ~2 MB.
 
 The vjp-fwd variant additionally saves each chunk's *incoming* carried
 state (b, nc, h, p, n) — O(l/chunk) memory instead of the O(l*chunk)
@@ -15,8 +24,8 @@ decay matrices jnp autodiff of the chunked ref would stash.  The backward
 (``ssd_scan_bwd``) walks the chunk axis in reverse (index maps flip the
 grid), carries dh_state in VMEM, and rebuilds each chunk's decay matrix
 on-chip, so dx/da/dB/dC cost one more pass over the same HBM traffic as
-the forward.  The backward materializes ~3 (c, c, h) intermediates in
-VMEM; prefer chunk<=128 at large h on real hardware.
+the forward.  dB/dC sum over heads: they accumulate in VMEM across the
+innermost head axis and are written once per chunk.
 """
 from __future__ import annotations
 
@@ -28,49 +37,79 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _ssd_kernel(x_ref, a_ref, b_ref, c_ref, y_ref, hfin_ref, h_sc, *,
-                nc: int):
+def _mm(a, b, contract=((1,), (0,)), precision=None):
+    return jax.lax.dot_general(a, b, (contract, ((), ())),
+                               precision=precision,
+                               preferred_element_type=jnp.float32)
+
+
+def _chunk_decays(ccol_ref, crow_ref, n: int):
+    """The chunk's cumulative log-decays as a column (c, 1), the (c, c)
+    causal decay matrix L[l, s] = exp(cum[l] - cum[s]) for l >= s else 0,
+    and the chunk total cum[-1] repeated along a (1, n) row.  The row is
+    an exact one-hot matmul: the compiler cannot splat a (1, 1) value
+    over a (p, n) tile."""
+    cum = ccol_ref[0, 0]                            # (c, 1) fp32
+    cum_row = crow_ref[0, 0]                        # (1, c)
+    c_len = cum.shape[0]
+    ii = jax.lax.broadcasted_iota(jnp.int32, (c_len, c_len), 0)
+    jj = jax.lax.broadcasted_iota(jnp.int32, (c_len, c_len), 1)
+    L = jnp.where(ii >= jj, jnp.exp(cum - cum_row), 0.0)
+    last = jax.lax.broadcasted_iota(jnp.int32, (c_len, n), 0) == c_len - 1
+    total = _mm(cum_row, last.astype(jnp.float32),   # (1, n)
+                precision=jax.lax.Precision.HIGHEST)
+    return cum, L, total
+
+
+def _ssd_kernel(x_ref, ccol_ref, crow_ref, b_ref, c_ref, y_ref, hfin_ref,
+                *rest, nc: int, save_residuals: bool):
+    if save_residuals:
+        hprev_ref, h_sc, sc_sc = rest
+    else:
+        h_sc, sc_sc = rest
     ci = pl.program_id(1)
+    hi = pl.program_id(2)
 
     @pl.when(ci == 0)
     def _init():
-        h_sc[...] = jnp.zeros_like(h_sc)
+        h_sc[hi] = jnp.zeros(h_sc.shape[1:], jnp.float32)
 
-    x = x_ref[0].astype(jnp.float32)                # (c, h, p)
-    a = a_ref[0].astype(jnp.float32)                # (c, h)
     B = b_ref[0].astype(jnp.float32)                # (c, n)
     C = c_ref[0].astype(jnp.float32)                # (c, n)
-    c_len = x.shape[0]
 
-    cum = jnp.cumsum(a, axis=0)                     # (c, h)
-    seg = cum[:, None, :] - cum[None, :, :]         # (l, s, h)
-    ii = jax.lax.broadcasted_iota(jnp.int32, (c_len, c_len), 0)
-    jj = jax.lax.broadcasted_iota(jnp.int32, (c_len, c_len), 1)
-    L = jnp.where((ii >= jj)[:, :, None], jnp.exp(seg), 0.0)
+    @pl.when(hi == 0)
+    def _scores():
+        sc_sc[...] = _mm(C, B, ((1,), (1,)))        # (l, s)
 
-    scores = jax.lax.dot_general(C, B, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-    y_diag = jnp.einsum("ls,lsh,shp->lhp", scores, L, x)
-    hprev = h_sc[...]                               # (h, p, n)
-    y_off = jnp.einsum("ln,hpn,lh->lhp", C, hprev, jnp.exp(cum))
+    x = x_ref[0, 0].astype(jnp.float32)             # (c, p)
+    cum, L, total = _chunk_decays(ccol_ref, crow_ref, B.shape[1])
+    cum_last = cum[-1:, :]                          # (1, 1)
+    hprev = h_sc[hi]                                # (p, n)
+    if save_residuals:
+        hprev_ref[0, 0, 0] = hprev
 
-    decay_end = jnp.exp(cum[-1, :][None, :] - cum)  # (c, h)
-    h_new = jnp.einsum("sh,shp,sn->hpn", decay_end, x, B)
-    h_sc[...] = h_new + hprev * jnp.exp(cum[-1, :])[:, None, None]
+    y_diag = _mm(sc_sc[...] * L, x)                 # (c, p)
+    y_off = _mm(C, hprev, ((1,), (1,))) * jnp.exp(cum)
+    decay_end = jnp.exp(cum_last - cum)             # (c, 1)
+    h_new = _mm(x * decay_end, B, ((0,), (0,)))     # (p, n)
+    h_sc[hi] = h_new + hprev * jnp.exp(total)
 
-    y_ref[0] = (y_diag + y_off).astype(y_ref.dtype)
+    y_ref[0, 0] = (y_diag + y_off).astype(y_ref.dtype)
 
     @pl.when(ci == nc - 1)
     def _fin():
-        hfin_ref[0] = h_sc[...]
+        hfin_ref[0, 0] = h_sc[hi]
 
 
-def _ssd_res_kernel(x_ref, a_ref, b_ref, c_ref, y_ref, hfin_ref, hprev_ref,
-                    h_sc, *, nc: int):
-    """Forward + save the chunk's incoming carried state (vjp residual)."""
-    hprev_ref[0, 0] = jnp.where(pl.program_id(1) == 0,
-                                jnp.zeros_like(h_sc), h_sc[...])
-    _ssd_kernel(x_ref, a_ref, b_ref, c_ref, y_ref, hfin_ref, h_sc, nc=nc)
+def _head_major(x, a, c: int):
+    """(b, l, h, p) -> (b, h, l, p); log-decays (b, l, h) -> their
+    inclusive cumsum within each chunk of ``c`` steps, as per-head columns
+    (b, h, l, 1) and rows (b, h, 1, l)."""
+    b, l, h = a.shape
+    cum = jnp.cumsum(a.astype(jnp.float32).reshape(b, l // c, c, h), axis=2)
+    cum = jnp.transpose(cum.reshape(b, l, h), (0, 2, 1))
+    return (jnp.transpose(x, (0, 2, 1, 3)), cum[..., None],
+            cum[:, :, None, :])
 
 
 def ssd_scan_fwd(x, a, B, C, *, chunk=256, interpret=False,
@@ -84,112 +123,117 @@ def ssd_scan_fwd(x, a, B, C, *, chunk=256, interpret=False,
     c = min(chunk, l)
     assert l % c == 0
     nc = l // c
+    xt, ccol, crow = _head_major(x, a, c)
     in_specs = [
-        pl.BlockSpec((1, c, h, p), lambda bi, ci: (bi, ci, 0, 0)),
-        pl.BlockSpec((1, c, h), lambda bi, ci: (bi, ci, 0)),
-        pl.BlockSpec((1, c, n), lambda bi, ci: (bi, ci, 0)),
-        pl.BlockSpec((1, c, n), lambda bi, ci: (bi, ci, 0)),
+        pl.BlockSpec((1, 1, c, p), lambda bi, ci, hi: (bi, hi, ci, 0)),
+        pl.BlockSpec((1, 1, c, 1), lambda bi, ci, hi: (bi, hi, ci, 0)),
+        pl.BlockSpec((1, 1, 1, c), lambda bi, ci, hi: (bi, hi, 0, ci)),
+        pl.BlockSpec((1, c, n), lambda bi, ci, hi: (bi, ci, 0)),
+        pl.BlockSpec((1, c, n), lambda bi, ci, hi: (bi, ci, 0)),
     ]
     out_specs = [
-        pl.BlockSpec((1, c, h, p), lambda bi, ci: (bi, ci, 0, 0)),
-        pl.BlockSpec((1, h, p, n), lambda bi, ci: (bi, 0, 0, 0)),
+        pl.BlockSpec((1, 1, c, p), lambda bi, ci, hi: (bi, hi, ci, 0)),
+        pl.BlockSpec((1, 1, p, n), lambda bi, ci, hi: (bi, hi, 0, 0)),
     ]
     out_shape = [
-        jax.ShapeDtypeStruct((b, l, h, p), x.dtype),
+        jax.ShapeDtypeStruct((b, h, l, p), x.dtype),
         jax.ShapeDtypeStruct((b, h, p, n), jnp.float32),
     ]
     if save_residuals:
-        out_specs.append(
-            pl.BlockSpec((1, 1, h, p, n), lambda bi, ci: (bi, ci, 0, 0, 0)))
+        out_specs.append(pl.BlockSpec(
+            (1, 1, 1, p, n), lambda bi, ci, hi: (bi, ci, hi, 0, 0)))
         out_shape.append(
             jax.ShapeDtypeStruct((b, nc, h, p, n), jnp.float32))
-        kernel = functools.partial(_ssd_res_kernel, nc=nc)
-    else:
-        kernel = functools.partial(_ssd_kernel, nc=nc)
-    return pl.pallas_call(
-        kernel,
-        grid=(b, nc),
+    out = pl.pallas_call(
+        functools.partial(_ssd_kernel, nc=nc,
+                          save_residuals=save_residuals),
+        grid=(b, nc, h),
         in_specs=in_specs,
         out_specs=out_specs,
         out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((h, p, n), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((h, p, n), jnp.float32),
+                        pltpu.VMEM((c, c), jnp.float32)],
         interpret=interpret,
-    )(x, a, B, C)
+    )(xt, ccol, crow, B, C)
+    return (jnp.transpose(out[0], (0, 2, 1, 3)),) + tuple(out[1:])
 
 
-def _ssd_bwd_kernel(x_ref, a_ref, b_ref, c_ref, hprev_ref, dy_ref, dhfin_ref,
-                    dx_ref, da_ref, db_ref, dc_ref, dh_sc):
-    """One reverse-recurrence step: grads for chunk ``nc - 1 - ci``.
+def _ssd_bwd_kernel(x_ref, ccol_ref, crow_ref, b_ref, c_ref, hprev_ref,
+                    dy_ref, dhfin_ref, dx_ref, dcum_ref, db_ref, dc_ref,
+                    dh_sc, sc_sc, db_sc, dc_sc, *, n_heads: int):
+    """One reverse-recurrence step: grads for chunk ``nc - 1 - ci``, head
+    ``hi``.
 
-    ``dh_sc`` carries dL/d(state entering the *next* chunk); at ci == 0
-    (the last chunk) that is the caller's dL/d(h_final) cotangent.
+    ``dh_sc[hi]`` carries dL/d(state entering the *next* chunk); at
+    ci == 0 (the last chunk) that is the caller's dL/d(h_final) cotangent.
     """
     ci = pl.program_id(1)
+    hi = pl.program_id(2)
 
     @pl.when(ci == 0)
     def _init():
-        dh_sc[...] = dhfin_ref[0]
+        dh_sc[hi] = dhfin_ref[0, 0]
 
-    x = x_ref[0].astype(jnp.float32)                # (c, h, p)
-    a = a_ref[0].astype(jnp.float32)                # (c, h)
     B = b_ref[0].astype(jnp.float32)                # (c, n)
     C = c_ref[0].astype(jnp.float32)                # (c, n)
-    hin = hprev_ref[0, 0]                           # (h, p, n) fp32
-    dy = dy_ref[0].astype(jnp.float32)              # (c, h, p)
-    dhout = dh_sc[...]                              # (h, p, n)
+
+    @pl.when(hi == 0)
+    def _scores():
+        sc_sc[...] = _mm(C, B, ((1,), (1,)))        # (l, s)
+        db_sc[...] = jnp.zeros_like(db_sc)
+        dc_sc[...] = jnp.zeros_like(dc_sc)
+
+    x = x_ref[0, 0].astype(jnp.float32)             # (c, p)
+    hin = hprev_ref[0, 0, 0]                        # (p, n) fp32
+    dy = dy_ref[0, 0].astype(jnp.float32)           # (c, p)
+    dhout = dh_sc[hi]                               # (p, n)
+    cum, L, total = _chunk_decays(ccol_ref, crow_ref, B.shape[1])
     c_len = x.shape[0]
+    ecum = jnp.exp(cum)                             # (c, 1)
+    ecum_last = ecum[-1:, :]                        # (1, 1)
+    scores = sc_sc[...]
+    ones = jnp.ones((c_len, 1), jnp.float32)
 
-    cum = jnp.cumsum(a, axis=0)                     # (c, h)
-    ecum = jnp.exp(cum)
-    ecum_last = jnp.exp(cum[-1, :])                 # (h,)
-    seg = cum[:, None, :] - cum[None, :, :]         # (l, s, h)
-    ii = jax.lax.broadcasted_iota(jnp.int32, (c_len, c_len), 0)
-    jj = jax.lax.broadcasted_iota(jnp.int32, (c_len, c_len), 1)
-    tril = (ii >= jj)[:, :, None]
-    L = jnp.where(tril, jnp.exp(seg), 0.0)          # (l, s, h)
-    scores = jax.lax.dot_general(C, B, (((1,), (1,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-
-    # ---- intra-chunk (diag) term:  y_diag = einsum(scores, L, x) ----
-    G = jnp.einsum("shp,lhp->lsh", x, dy)           # sum_p x[s] dy[l]
+    # ---- intra-chunk (diag) term:  y_diag = (scores * L) @ x ----
+    G = _mm(dy, x, ((1,), (1,)))                    # (l, s)
     LG = L * G
-    dscores = jnp.sum(LG, axis=-1)                  # (l, s)
-    dx = jnp.einsum("lsh,lhp->shp", scores[:, :, None] * L, dy)
-    dC = jax.lax.dot_general(dscores, B, (((1,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    dB = jax.lax.dot_general(dscores, C, (((0,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    dseg_sum_s = jnp.sum(scores[:, :, None] * LG, axis=1)   # (l, h)
-    dseg_sum_l = jnp.sum(scores[:, :, None] * LG, axis=0)   # (s, h)
+    dx = _mm(scores * L, dy, ((0,), (0,)))          # (s, p)
+    dC = _mm(LG, B)                                 # (l, n)
+    dB = _mm(LG, C, ((0,), (0,)))                   # (s, n)
+    SLG = scores * LG
+    dseg_sum_s = jnp.sum(SLG, axis=1, keepdims=True)        # (l, 1)
+    dseg_sum_l = _mm(SLG, ones, ((0,), (0,)))               # (s, 1)
 
-    # ---- inter-chunk term:  y_off = einsum(C, h_in, exp(cum)) ----
-    hC = jnp.einsum("lhp,hpn->lhn", dy, hin)
-    dC = dC + jnp.einsum("lhn,lh->ln", hC, ecum)
-    dhin = jnp.einsum("lh,lhp,ln->hpn", ecum, dy, C)
-    dcum = dseg_sum_s - dseg_sum_l + ecum * jnp.einsum("lhn,ln->lh", hC, C)
+    # ---- inter-chunk term:  y_off = (C @ h_in^T) * exp(cum) ----
+    hC = _mm(dy, hin)                               # (l, n)
+    dC = dC + hC * ecum
+    dhin = _mm(dy * ecum, C, ((0,), (0,)))          # (p, n)
+    dcum = (dseg_sum_s - dseg_sum_l
+            + ecum * jnp.sum(hC * C, axis=1, keepdims=True))
 
-    # ---- state carry:  h_out = einsum(decay_end, x, B) + h_in*exp(cum_c) ----
-    de = jnp.exp(cum[-1, :][None, :] - cum)         # (s, h)
-    Bdh = jnp.einsum("sn,hpn->shp", B, dhout)
-    dx = dx + de[:, :, None] * Bdh
-    dB = dB + jnp.einsum("sh,shp,hpn->sn", de, x, dhout)
-    dde = jnp.sum(x * Bdh, axis=-1)                 # (s, h)
-    dhin = dhin + dhout * ecum_last[:, None, None]
+    # ---- state carry:  h_out = (x * de)^T @ B + h_in * exp(cum_c) ----
+    de = jnp.exp(cum[-1:, :] - cum)                 # (s, 1)
+    Bdh = _mm(B, dhout, ((1,), (1,)))               # (s, p)
+    dx = dx + de * Bdh
+    dB = dB + de * _mm(x, dhout)                    # (s, n)
+    dde = jnp.sum(x * Bdh, axis=1, keepdims=True)   # (s, 1)
+    dhin = dhin + dhout * jnp.exp(total)
     dcum = dcum - de * dde
-    dcum_last = (jnp.sum(de * dde, axis=0) +
-                 ecum_last * jnp.einsum("hpn,hpn->h", hin, dhout))   # (h,)
-    row = jax.lax.broadcasted_iota(jnp.int32, (c_len, a.shape[-1]), 0)
-    dcum = dcum + jnp.where(row == c_len - 1, dcum_last[None, :], 0.0)
+    dcum_last = (jnp.sum(de * dde, axis=0, keepdims=True)
+                 + ecum_last * jnp.sum(hin * dhout, keepdims=True))
+    row = jax.lax.broadcasted_iota(jnp.int32, (c_len, 1), 0)
+    dcum = dcum + jnp.where(row == c_len - 1, dcum_last, 0.0)
 
-    # da[t] = sum_{u>=t} dcum[u]  (reverse cumsum, flip-free)
-    s_ = jnp.cumsum(dcum, axis=0)
-    da = s_[-1:, :] - s_ + dcum
+    dx_ref[0, 0] = dx.astype(dx_ref.dtype)
+    dcum_ref[0, 0] = dcum
+    db_sc[...] += dB
+    dc_sc[...] += dC
+    dh_sc[hi] = dhin
 
-    dx_ref[0] = dx.astype(dx_ref.dtype)
-    da_ref[0] = da.astype(da_ref.dtype)
-    db_ref[0] = dB.astype(db_ref.dtype)
-    dc_ref[0] = dC.astype(dc_ref.dtype)
-    dh_sc[...] = dhin
+    @pl.when(hi == n_heads - 1)
+    def _write_bc():
+        db_ref[0] = db_sc[...].astype(db_ref.dtype)
+        dc_ref[0] = dc_sc[...].astype(dc_ref.dtype)
 
 
 def ssd_scan_bwd(x, a, B, C, hprev, dy, dhfin, *, chunk=256,
@@ -205,35 +249,54 @@ def ssd_scan_bwd(x, a, B, C, hprev, dy, dhfin, *, chunk=256,
     assert l % c == 0
     nc = l // c
     assert hprev.shape == (b, nc, h, p, n), (hprev.shape, (b, nc, h, p, n))
+    xt, ccol, crow = _head_major(x, a, c)
+    dyt = jnp.transpose(dy, (0, 2, 1, 3))
 
     def rev(ci):
         return nc - 1 - ci
 
-    return pl.pallas_call(
-        _ssd_bwd_kernel,
-        grid=(b, nc),
+    dx, dcum, dB, dC = pl.pallas_call(
+        functools.partial(_ssd_bwd_kernel, n_heads=h),
+        grid=(b, nc, h),
         in_specs=[
-            pl.BlockSpec((1, c, h, p), lambda bi, ci: (bi, rev(ci), 0, 0)),
-            pl.BlockSpec((1, c, h), lambda bi, ci: (bi, rev(ci), 0)),
-            pl.BlockSpec((1, c, n), lambda bi, ci: (bi, rev(ci), 0)),
-            pl.BlockSpec((1, c, n), lambda bi, ci: (bi, rev(ci), 0)),
-            pl.BlockSpec((1, 1, h, p, n),
-                         lambda bi, ci: (bi, rev(ci), 0, 0, 0)),
-            pl.BlockSpec((1, c, h, p), lambda bi, ci: (bi, rev(ci), 0, 0)),
-            pl.BlockSpec((1, h, p, n), lambda bi, ci: (bi, 0, 0, 0)),
+            pl.BlockSpec((1, 1, c, p),
+                         lambda bi, ci, hi: (bi, hi, rev(ci), 0)),
+            pl.BlockSpec((1, 1, c, 1),
+                         lambda bi, ci, hi: (bi, hi, rev(ci), 0)),
+            pl.BlockSpec((1, 1, 1, c),
+                         lambda bi, ci, hi: (bi, hi, 0, rev(ci))),
+            pl.BlockSpec((1, c, n), lambda bi, ci, hi: (bi, rev(ci), 0)),
+            pl.BlockSpec((1, c, n), lambda bi, ci, hi: (bi, rev(ci), 0)),
+            pl.BlockSpec((1, 1, 1, p, n),
+                         lambda bi, ci, hi: (bi, rev(ci), hi, 0, 0)),
+            pl.BlockSpec((1, 1, c, p),
+                         lambda bi, ci, hi: (bi, hi, rev(ci), 0)),
+            pl.BlockSpec((1, 1, p, n), lambda bi, ci, hi: (bi, hi, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, c, h, p), lambda bi, ci: (bi, rev(ci), 0, 0)),
-            pl.BlockSpec((1, c, h), lambda bi, ci: (bi, rev(ci), 0)),
-            pl.BlockSpec((1, c, n), lambda bi, ci: (bi, rev(ci), 0)),
-            pl.BlockSpec((1, c, n), lambda bi, ci: (bi, rev(ci), 0)),
+            pl.BlockSpec((1, 1, c, p),
+                         lambda bi, ci, hi: (bi, hi, rev(ci), 0)),
+            pl.BlockSpec((1, 1, c, 1),
+                         lambda bi, ci, hi: (bi, hi, rev(ci), 0)),
+            pl.BlockSpec((1, c, n), lambda bi, ci, hi: (bi, rev(ci), 0)),
+            pl.BlockSpec((1, c, n), lambda bi, ci, hi: (bi, rev(ci), 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b, l, h, p), x.dtype),
-            jax.ShapeDtypeStruct((b, l, h), a.dtype),
+            jax.ShapeDtypeStruct((b, h, l, p), x.dtype),
+            jax.ShapeDtypeStruct((b, h, l, 1), jnp.float32),
             jax.ShapeDtypeStruct((b, l, n), B.dtype),
             jax.ShapeDtypeStruct((b, l, n), C.dtype),
         ],
-        scratch_shapes=[pltpu.VMEM((h, p, n), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((h, p, n), jnp.float32),
+                        pltpu.VMEM((c, c), jnp.float32),
+                        pltpu.VMEM((c, n), jnp.float32),
+                        pltpu.VMEM((c, n), jnp.float32)],
         interpret=interpret,
-    )(x, a, B, C, hprev, dy, dhfin)
+    )(xt, ccol, crow, B, C, hprev, dyt, dhfin)
+    # da[t] = sum_{u>=t} dcum[u] within each chunk (reverse cumsum,
+    # flip-free)
+    dcum = dcum.reshape(b, h, nc, c)
+    s_ = jnp.cumsum(dcum, axis=-1)
+    da = (s_[..., -1:] - s_ + dcum).reshape(b, h, l)
+    return (jnp.transpose(dx, (0, 2, 1, 3)),
+            jnp.transpose(da, (0, 2, 1)).astype(a.dtype), dB, dC)

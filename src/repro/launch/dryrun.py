@@ -1,7 +1,10 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
-# ^ MUST be the first two lines: jax locks the device count on first init.
+# ^ MUST precede any jax import: jax locks the device count on first init,
+# and 512 fake CPU devices stand in for the production meshes (this tool
+# never opens an accelerator; its children inherit both variables).
 """Multi-pod dry-run: lower + compile every (arch x shape) on the production
 meshes with ShapeDtypeStruct stand-ins (no allocation).
 
@@ -20,6 +23,7 @@ import subprocess
 import sys
 import traceback
 
+from repro import hw
 from repro.telemetry import now
 
 
@@ -156,6 +160,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     result = {
         "arch": arch, "shape": shape_name,
         "mesh": "2x16x16" if multi_pod else "16x16",
+        "device_kind": hw.DRYRUN_DEVICE_KIND,
         "n_devices": int(len(mesh.devices.flat)),
         "ok": True,
         "lower_s": round(t_lower, 2), "compile_s": round(t_compile, 2),

@@ -76,6 +76,8 @@ def main(argv=None):
                     help="write a Chrome-trace JSON (chrome://tracing / "
                          "Perfetto) of the run to this path")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from repro.configs.registry import get_arch, smoke_config
     from repro.data.synthetic import batch_for_model
@@ -86,7 +88,7 @@ def main(argv=None):
         cfg = dataclasses.replace(cfg, compute_dtype="float32")
     impl = args.decode_impl or cfg.decode_impl
     model = build_model(cfg)
-    params = model.init(jax.random.PRNGKey(args.seed))
+    params = init_params(model, args.seed)
 
     batch = batch_for_model(cfg, "prefill", 0, args.batch, args.prompt_len,
                             args.seed)
@@ -108,6 +110,19 @@ def main(argv=None):
             uninstall_writer()
             writer.write(args.trace)
             print(f"trace written to {args.trace}")
+
+
+def init_params(model, seed: int):
+    """Random weights from ``seed``, held in the model's compute dtype.
+    Each fp32 leaf is drawn and cast inside one jit, so a full-width
+    model's fp32 masters are never materialized on the device: serving
+    keeps no masters (phi4-mini-3.8b: 7.7 GB in bf16, not 15.4 GB)."""
+    dtype = jnp.dtype(model.compute_dtype)
+
+    def make(key):
+        return jax.tree_util.tree_map(lambda w: w.astype(dtype),
+                                      model.init(key))
+    return jax.jit(make)(jax.random.PRNGKey(seed))
 
 
 def _print_stats(stats, request_metrics=None):
@@ -192,7 +207,7 @@ def _spec_kwargs(model, args):
                                    n_layers=max(1, model.cfg.n_layers // 2))
         dmodel = build_model(dcfg)
         kw["draft_model"] = dmodel
-        kw["draft_params"] = dmodel.init(jax.random.PRNGKey(args.seed + 1))
+        kw["draft_params"] = init_params(dmodel, args.seed + 1)
     return kw
 
 

@@ -28,6 +28,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.mesh import make_mesh
+
 
 def build_mesh(parallel: str, pp_stages: int = 1):
     """Axis layout per executor (all degenerate axes keep size 1)."""
@@ -35,15 +37,15 @@ def build_mesh(parallel: str, pp_stages: int = 1):
     if parallel == "ddp":
         # weak "pod" axis first: a single host has no pod boundary, so
         # pods=1 and HFReduce's cross-pod phase is a no-op
-        return jax.make_mesh((1, n), ("pod", "data"))
+        return make_mesh((1, n), ("pod", "data"))
     if parallel == "pp":
         if n % pp_stages:
             raise SystemExit(f"--pp-stages {pp_stages} does not divide "
                              f"{n} devices")
-        return jax.make_mesh((pp_stages, 1, n // pp_stages),
+        return make_mesh((pp_stages, 1, n // pp_stages),
                              ("pipe", "pod", "data"))
-    return jax.make_mesh((1, len(jax.devices())), ("data", "model")) \
-        if n > 1 else jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, len(jax.devices())), ("data", "model")) \
+        if n > 1 else make_mesh((1, 1), ("data", "model"))
 
 
 def build_plan(args) -> "object":
@@ -131,6 +133,8 @@ def main(argv=None):
                     help="write a Chrome-trace JSON (chrome://tracing / "
                          "Perfetto) of the run to this path")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.ddp:
         warnings.warn("--ddp is deprecated; use --parallel ddp",
                       DeprecationWarning, stacklevel=2)

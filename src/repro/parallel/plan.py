@@ -141,7 +141,9 @@ def make_train_step(plan: ParallelPlan, model, optimizer, mesh, *,
 
     The returned callable is wrapped in a host-side ``train.step``
     telemetry span *outside* the jit boundary (dispatch wall time, mode
-    attr) — every executor gets the same trace shape for free.
+    attr) — every executor gets the same trace shape for free.  The
+    jitted step itself is its ``jitted`` attribute (AOT ``lower`` /
+    ``compile``, e.g. to inspect the compiled program).
     """
     import jax
 
@@ -174,6 +176,7 @@ def make_train_step(plan: ParallelPlan, model, optimizer, mesh, *,
         with span("train.step", mode=mode):
             return step(state, batch)
 
+    traced_step.jitted = step
     return traced_step
 
 

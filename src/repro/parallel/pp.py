@@ -32,8 +32,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from repro.core.axis import axis_size
-
 
 def split_stages(stacked_params, n_stages: int):
     """(L, ...) stacked layer params -> (P, L/P, ...) for P("pipe") sharding."""
@@ -54,7 +52,7 @@ def pipeline_apply(stage_fn, stage_params, x_micro, *, axis: str = "pipe"):
     Returns (n_micro, mb, ...) outputs, valid on every rank (psum-broadcast
     from the last stage).
     """
-    P = axis_size(axis)
+    P = lax.axis_size(axis)
     rank = lax.axis_index(axis)
     n_micro = x_micro.shape[0]
     sp = jax.tree_util.tree_map(lambda a: a[0], stage_params)
@@ -86,7 +84,6 @@ def make_pipelined_forward(layer_fn, n_stages: int, n_micro: int, mesh,
     layer_fn(layer_params, x) -> x;  stacked_params: (L, ...) trees;
     x: (batch, ...) with batch % n_micro == 0.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as Pspec
 
     def stage_fn(sp, x):
@@ -98,11 +95,11 @@ def make_pipelined_forward(layer_fn, n_stages: int, n_micro: int, mesh,
     def inner(staged_params, x_micro):
         return pipeline_apply(stage_fn, staged_params, x_micro, axis=axis)
 
-    sharded = shard_map(
+    sharded = jax.shard_map(
         inner, mesh=mesh,
         in_specs=(Pspec(axis), Pspec()),
         out_specs=Pspec(),
-        check_rep=False)
+        check_vma=False)
 
     def f(stacked_params, x):
         b = x.shape[0]
@@ -172,7 +169,6 @@ def make_pp_train_step(model, optimizer, mesh, plan, *,
     is exactly ``optimizer.init(params)`` and the loss trajectory matches
     the single-stage step up to float reassociation.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as Pspec
     from repro.core import bucketing
     from repro.core.ddp import make_ddp_grad_sync
@@ -373,9 +369,9 @@ def make_pp_train_step(model, optimizer, mesh, plan, *,
 
     batch_spec = Pspec(batch_axes if len(batch_axes) > 1 else
                        (batch_axes[0] if batch_axes else None))
-    step = shard_map(
+    step = jax.shard_map(
         local_step, mesh=mesh,
         in_specs=(Pspec(), batch_spec),
         out_specs=(Pspec(), Pspec()),
-        check_rep=False)
+        check_vma=False)
     return jax.jit(step, **(dict(donate_argnums=(0,)) if donate else {}))

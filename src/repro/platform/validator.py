@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.launch.mesh import make_mesh
 from repro.telemetry import now, span
 
 
@@ -87,11 +88,10 @@ class Validator:
         devs = jax.devices()
         x = jnp.ones((len(devs), 1024), jnp.float32)
         try:
-            from jax.experimental.shard_map import shard_map
             from jax.sharding import PartitionSpec as P
-            mesh = jax.make_mesh((len(devs),), ("d",))
-            out = shard_map(lambda v: jax.lax.psum(v, "d"), mesh=mesh,
-                            in_specs=P("d"), out_specs=P("d"))(x)
+            mesh = make_mesh((len(devs),), ("d",))
+            out = jax.shard_map(lambda v: jax.lax.psum(v, "d"), mesh=mesh,
+                                in_specs=P("d"), out_specs=P("d"))(x)
             ok = bool(jnp.all(out == float(len(devs))))
         except Exception as e:  # pragma: no cover
             return CheckResult("intra_node_allreduce", False, 0, "",

@@ -44,14 +44,27 @@ from repro.models.attention import KV_DTYPES, quantize_kv
 from repro.serve_lib import _prefix_key
 
 
-# Donate the pools where donation works so admissions/COW copies update
-# in place instead of rewriting O(pool) HBM; CPU rejects donation with a
-# warning, so keep it off there.  Callers immediately rebind self.k/v.
-_DONATE = (0, 1) if jax.default_backend() in ("tpu", "gpu") else ()
-_DONATE_Q = (0, 1, 2, 3) if jax.default_backend() in ("tpu", "gpu") else ()
+def _pool_update(n_pools: int):
+    """jit a pool-writing function, donating its first ``n_pools``
+    arguments where donation works, so admissions/COW copies update in
+    place instead of rewriting O(pool) HBM; CPU rejects donation with a
+    warning, so keep it off there.  The backend is asked at the first
+    call, never at import.  Callers immediately rebind self.k/v."""
+    def wrap(fn):
+        @functools.cache
+        def jitted():
+            donate = (tuple(range(n_pools))
+                      if jax.default_backend() in ("tpu", "gpu") else ())
+            return jax.jit(fn, donate_argnums=donate)
+
+        @functools.wraps(fn)
+        def call(*args):
+            return jitted()(*args)
+        return call
+    return wrap
 
 
-@functools.partial(jax.jit, donate_argnums=_DONATE)
+@_pool_update(2)
 def _scatter_blocks(k_pool, v_pool, k, v, block_ids):
     """Write dense prefill K/V (L, nblk*bs, kv, hd) into pool blocks."""
     L, nb, bs, kvh, hd = k_pool.shape
@@ -60,7 +73,7 @@ def _scatter_blocks(k_pool, v_pool, k, v, block_ids):
     return k_pool.at[:, block_ids].set(kb), v_pool.at[:, block_ids].set(vb)
 
 
-@functools.partial(jax.jit, donate_argnums=_DONATE_Q)
+@_pool_update(4)
 def _scatter_blocks_quant(k_pool, v_pool, ks_pool, vs_pool, k, v, block_ids):
     """Quantize-on-write for sub-bf16 pools: dense prefill K/V
     (L, nblk*bs, kv, hd) is quantized per token entry (absmax over
@@ -78,7 +91,7 @@ def _scatter_blocks_quant(k_pool, v_pool, ks_pool, vs_pool, k, v, block_ids):
             vs_pool.at[:, block_ids].set(vsb))
 
 
-@functools.partial(jax.jit, donate_argnums=_DONATE)
+@_pool_update(2)
 def _set_blocks(k_pool, v_pool, kb, vb, block_ids):
     """Write already-blocked K/V (L, n, bs, kv, hd) into pool blocks —
     the cross-replica import path (contents arrive pre-blocked and, for
@@ -87,7 +100,7 @@ def _set_blocks(k_pool, v_pool, kb, vb, block_ids):
             v_pool.at[:, block_ids].set(vb.astype(v_pool.dtype)))
 
 
-@functools.partial(jax.jit, donate_argnums=_DONATE_Q)
+@_pool_update(4)
 def _set_blocks_quant(k_pool, v_pool, ks_pool, vs_pool, kb, vb, ksb, vsb,
                       block_ids):
     return (k_pool.at[:, block_ids].set(kb.astype(k_pool.dtype)),
@@ -96,13 +109,13 @@ def _set_blocks_quant(k_pool, v_pool, ks_pool, vs_pool, kb, vb, ksb, vsb,
             vs_pool.at[:, block_ids].set(vsb.astype(vs_pool.dtype)))
 
 
-@functools.partial(jax.jit, donate_argnums=_DONATE)
+@_pool_update(2)
 def _copy_block(k_pool, v_pool, src, dst):
     return (k_pool.at[:, dst].set(k_pool[:, src]),
             v_pool.at[:, dst].set(v_pool[:, src]))
 
 
-@functools.partial(jax.jit, donate_argnums=_DONATE_Q)
+@_pool_update(4)
 def _copy_block_quant(k_pool, v_pool, ks_pool, vs_pool, src, dst):
     """COW copy carrying the per-token scale rows with the block — a
     quantized block without its scales dequantizes to garbage, so the

@@ -1,7 +1,9 @@
 import os
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
+os.environ["JAX_PLATFORMS"] = "cpu"
 
-# ^ must precede any jax import: collective tests need >1 (fake) device.
+# ^ must precede any jax import: collective tests need >1 (fake) CPU
+# device, and a fake-device process must never open an accelerator.
 """Multi-device numerics checks, run as a subprocess from pytest so the
 main test process keeps its single-device jax. Prints one JSON report."""
 import json
@@ -10,12 +12,19 @@ import sys
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
+
+from repro.launch.mesh import make_mesh
+
+
+def _smap(f, mesh, in_specs, x):
+    """``f`` per shard of ``x`` under jit, with a replicated result."""
+    return jax.jit(jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                                 out_specs=P(), check_vma=False))(x)
 
 
 def _mesh():
-    return jax.make_mesh((2, 4), ("pod", "data"))
+    return make_mesh((2, 4), ("pod", "data"))
 
 
 def check_hfreduce():
@@ -30,10 +39,8 @@ def check_hfreduce():
         return flat_allreduce(v[0], axes=("pod", "data"))
 
     spec = P(("pod", "data"))
-    out_h = shard_map(f, mesh=mesh, in_specs=spec, out_specs=P(),
-                      check_rep=False)(x)
-    out_f = shard_map(g, mesh=mesh, in_specs=spec, out_specs=P(),
-                      check_rep=False)(x)
+    out_h = _smap(f, mesh, spec, x)
+    out_f = _smap(g, mesh, spec, x)
     ref = jnp.sum(x, axis=0)
     return (float(jnp.max(jnp.abs(out_h - ref))),
             float(jnp.max(jnp.abs(out_f - ref))))
@@ -41,7 +48,7 @@ def check_hfreduce():
 
 def check_tree_allreduce():
     from repro.core.tree_allreduce import tree_allreduce, ring_allreduce
-    mesh = jax.make_mesh((8,), ("n",))
+    mesh = make_mesh((8,), ("n",))
     x = jnp.asarray(np.random.default_rng(0).standard_normal((8, 257)),
                     jnp.float32)
 
@@ -52,17 +59,15 @@ def check_tree_allreduce():
         return ring_allreduce(v[0], "n")
 
     ref = jnp.sum(x, axis=0)
-    out_t = shard_map(t, mesh=mesh, in_specs=P("n"), out_specs=P(),
-                      check_rep=False)(x)
-    out_r = shard_map(r, mesh=mesh, in_specs=P("n"), out_specs=P(),
-                      check_rep=False)(x)
+    out_t = _smap(t, mesh, P("n"), x)
+    out_r = _smap(r, mesh, P("n"), x)
     return (float(jnp.max(jnp.abs(out_t - ref))),
             float(jnp.max(jnp.abs(out_r - ref))))
 
 
 def check_compressed_psum():
     from repro.core.compression import bf16_psum, int8_psum
-    mesh = jax.make_mesh((8,), ("n",))
+    mesh = make_mesh((8,), ("n",))
     rng = np.random.default_rng(1)
     x = jnp.asarray(rng.standard_normal((8, 4096)), jnp.float32)
     ref = np.asarray(jnp.sum(x, axis=0))
@@ -73,10 +78,8 @@ def check_compressed_psum():
     def fi(v):
         return int8_psum(v[0], "n")
 
-    out_b = np.asarray(shard_map(fb, mesh=mesh, in_specs=P("n"),
-                                 out_specs=P(), check_rep=False)(x))
-    out_i = np.asarray(shard_map(fi, mesh=mesh, in_specs=P("n"),
-                                 out_specs=P(), check_rep=False)(x))
+    out_b = np.asarray(_smap(fb, mesh, P("n"), x))
+    out_i = np.asarray(_smap(fi, mesh, P("n"), x))
     scale = np.abs(ref).max() + 1e-9
     return (float(np.max(np.abs(out_b - ref)) / scale),
             float(np.max(np.abs(out_i - ref)) / scale))
@@ -91,8 +94,7 @@ def check_hfreduce_tree_combo():
     def f(v):
         return hfreduce_tree(v[0], strong_axis="data", weak_axis="pod")
 
-    out = shard_map(f, mesh=mesh, in_specs=P(("pod", "data")), out_specs=P(),
-                    check_rep=False)(x)
+    out = _smap(f, mesh, P(("pod", "data")), x)
     ref = jnp.sum(x, axis=0)
     return float(jnp.max(jnp.abs(out - ref)))
 
@@ -263,10 +265,8 @@ def check_fp8_prescale():
                         weak_psum=fp8_psum) / 8.0
 
     spec = P(("pod", "data"))
-    out_fold = np.asarray(shard_map(fold, mesh=mesh, in_specs=spec,
-                                    out_specs=P(), check_rep=False)(x))
-    out_after = np.asarray(shard_map(after, mesh=mesh, in_specs=spec,
-                                     out_specs=P(), check_rep=False)(x))
+    out_fold = np.asarray(_smap(fold, mesh, spec, x))
+    out_after = np.asarray(_smap(after, mesh, spec, x))
     err_fold = float(np.max(np.abs(out_fold - ref)) / scale)
     err_after = float(np.max(np.abs(out_after - ref)) / scale)
     if not np.isfinite(err_after):
@@ -285,8 +285,9 @@ def check_pipeline():
     def layer_fn(w, h):
         return jnp.tanh(h @ w)
 
-    mesh = jax.make_mesh((4, 2), ("pipe", "dp"))
-    pp = make_pipelined_forward(layer_fn, n_stages=4, n_micro=m, mesh=mesh)
+    mesh = make_mesh((4, 2), ("pipe", "dp"))
+    pp = jax.jit(make_pipelined_forward(layer_fn, n_stages=4, n_micro=m,
+                                        mesh=mesh))
     y_pp = pp(W, x)
     y_seq = x
     for i in range(L):
@@ -302,8 +303,8 @@ def check_pipeline():
             h = layer_fn(w[i], h)
         return jnp.sum(h ** 2)
 
-    g_pp = jax.grad(loss_pp)(W)
-    g_seq = jax.grad(loss_seq)(W)
+    g_pp = jax.jit(jax.grad(loss_pp))(W)
+    g_seq = jax.jit(jax.grad(loss_seq))(W)
     grad_err = float(jnp.max(jnp.abs(g_pp - g_seq)))
     return fwd_err, grad_err
 
@@ -319,7 +320,7 @@ def check_pp_train():
 
     cfg, model, opt, params = _small_dense()
     state0 = opt.init(params)
-    mesh = jax.make_mesh((2, 2, 2), ("pipe", "pod", "data"))
+    mesh = make_mesh((2, 2, 2), ("pipe", "pod", "data"))
 
     def fetch(i):
         return {k: jnp.asarray(v)
@@ -392,9 +393,9 @@ def check_elastic_remesh():
         return state
 
     state0 = opt.init(model.init(jax.random.PRNGKey(0)))
-    mesh8 = jax.make_mesh((8, 1), ("data", "model"))
-    mesh4 = jax.sharding.Mesh(
-        np.array(jax.devices()[:4]).reshape(4, 1), ("data", "model"))
+    mesh8 = make_mesh((8, 1), ("data", "model"))
+    mesh4 = make_mesh((4, 1), ("data", "model"),
+                        devices=jax.devices()[:4])
 
     # unbroken 6-step reference on the large mesh
     ref = run_steps(mesh8, jax.tree_util.tree_map(jnp.copy, state0), 0, 6)
@@ -533,9 +534,9 @@ def check_elastic_cross_plan():
 
     cfg, model, _, params = _small_dense()
     opt = AdamW(lr=1e-3, param_dtype="float32")
-    mesh_pp = jax.make_mesh((2, 2, 2), ("pipe", "pod", "data"))
-    mesh_dp = jax.sharding.Mesh(
-        np.array(jax.devices()[:4]).reshape(1, 4), ("pod", "data"))
+    mesh_pp = make_mesh((2, 2, 2), ("pipe", "pod", "data"))
+    mesh_dp = make_mesh((1, 4), ("pod", "data"),
+                        devices=jax.devices()[:4])
     plan_pp = ParallelPlan(mode="pp", pp_microbatches=2)
     plan_dp = ParallelPlan(mode="ddp", zero1=True, overlap=False)
 

@@ -16,6 +16,7 @@ def _run_multidev():
         return _RESULT
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
     out = subprocess.run(
         [sys.executable, "-m", "repro.testing.multidev"],

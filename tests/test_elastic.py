@@ -16,6 +16,7 @@ from repro.ckpt import CheckpointManager
 from repro.elastic import (ElasticCheckpointer, PlanMismatchError,
                            canonical_state, master_layout, plan_from_dict,
                            plan_to_dict, plans_equal, reshard, save_sharded)
+from repro.launch.mesh import make_mesh
 from repro.optim import AdamW
 from repro.parallel.plan import ParallelPlan, init_state
 
@@ -28,6 +29,7 @@ def _run_elastic_harness():
         return _RESULT
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
     out = subprocess.run(
         [sys.executable, "-m", "repro.testing.multidev", "elastic"],
@@ -84,7 +86,7 @@ def test_sharded_roundtrip_and_plan_stamp(tmp_path):
     params = _params()
     opt = AdamW(lr=1e-2, param_dtype="float32")
     plan = ParallelPlan(mode="gspmd")
-    mesh = jax.make_mesh((1, 1), ("pod", "data"))
+    mesh = make_mesh((1, 1), ("pod", "data"))
     state = opt.init(params)
 
     mgr = save_sharded(state, plan, mesh, step=4,
@@ -104,7 +106,7 @@ def test_sharded_roundtrip_and_plan_stamp(tmp_path):
 def test_cross_plan_restore_requires_opt_in(tmp_path):
     params = _params()
     opt = AdamW(lr=1e-2, param_dtype="float32")
-    mesh = jax.make_mesh((1, 1), ("pod", "data"))
+    mesh = make_mesh((1, 1), ("pod", "data"))
     state = opt.init(params)
     mgr = save_sharded(state, ParallelPlan(mode="gspmd"), mesh, step=1,
                        root_or_backend=str(tmp_path))
@@ -128,7 +130,7 @@ def test_cross_plan_restore_requires_opt_in(tmp_path):
 def test_reshard_tree_to_zero1_and_back(tmp_path):
     params = _params()
     opt = AdamW(lr=1e-2, param_dtype="float32")
-    mesh = jax.make_mesh((1, 1), ("pod", "data"))
+    mesh = make_mesh((1, 1), ("pod", "data"))
     plan_t = ParallelPlan(mode="gspmd")
     plan_z = ParallelPlan(mode="ddp", zero1=True, overlap=False)
     state = opt.init(params)
@@ -158,7 +160,7 @@ def test_canonical_state_async_save(tmp_path):
     """Async sharded save lands the same canonical bytes as blocking."""
     params = _params()
     opt = AdamW(lr=1e-2, param_dtype="float32")
-    mesh = jax.make_mesh((1, 1), ("pod", "data"))
+    mesh = make_mesh((1, 1), ("pod", "data"))
     plan = ParallelPlan(mode="ddp", zero1=True, overlap=False)
     state = init_state(plan, opt, params, mesh)
 
@@ -181,7 +183,7 @@ def test_elastic_keeps_manager_gc(tmp_path):
     """Plan-stamped steps respect ``keep=`` like plain checkpoints."""
     params = _params()
     opt = AdamW(lr=1e-2, param_dtype="float32")
-    mesh = jax.make_mesh((1, 1), ("pod", "data"))
+    mesh = make_mesh((1, 1), ("pod", "data"))
     plan = ParallelPlan(mode="gspmd")
     state = opt.init(params)
     mgr = ElasticCheckpointer(str(tmp_path), plan, mesh, keep=2)

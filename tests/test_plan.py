@@ -63,7 +63,8 @@ def test_plan_lowers_to_parallel_config():
 def test_plan_ddp_requires_params_template():
     import jax
     from repro.parallel.plan import make_train_step
-    mesh = jax.make_mesh((1, 1), ("pod", "data"))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1, 1), ("pod", "data"))
     with pytest.raises(ValueError, match="params_template"):
         make_train_step(ParallelPlan(mode="ddp"), None, None, mesh)
 

@@ -108,13 +108,14 @@ def _tiny_setup():
     from repro.models import build_model
     from repro.optim import AdamW
     from repro import train_lib
+    from repro.launch.mesh import make_mesh
 
     cfg = dc.replace(smoke_config("phi4-mini-3.8b"), n_layers=2,
                      compute_dtype="float32")
     model = build_model(cfg)
     opt = AdamW(lr=1e-3, param_dtype="float32")
     state = opt.init(model.init(jax.random.PRNGKey(0)))
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     pcfg = ParallelConfig(tp=1, fsdp=False, batch_axes=("data",))
 
     def make_step(world):

@@ -34,6 +34,16 @@ def test_tpu_roofline_constants():
     assert hw.V5E.ici_bw_per_link == 50e9
 
 
+def test_peak_table_keyed_by_device_kind():
+    """Peaks are looked up by jax's device_kind; an unknown kind raises
+    instead of defaulting to v5e."""
+    assert hw.chip_spec("TPU v5 lite") is hw.V5E
+    with pytest.raises(KeyError, match="no peak table"):
+        hw.chip_spec("cpu")
+    with pytest.raises(KeyError):
+        hw.chip_spec(None)      # a dry-run record without device_kind
+
+
 def test_public_api_imports():
     import repro.core.hfreduce
     import repro.core.tree_allreduce
@@ -44,6 +54,34 @@ def test_public_api_imports():
     import repro.platform
     import repro.models
     import repro.launch.mesh
+
+
+_IMPORT_ALL = """
+import importlib, pkgutil
+import jax
+
+def boom(*args, **kwargs):
+    raise RuntimeError("JAX backend queried at import")
+
+jax.default_backend = jax.devices = boom
+import repro
+for mod in pkgutil.walk_packages(repro.__path__, "repro."):
+    importlib.import_module(mod.name)
+"""
+
+
+def test_imports_do_not_query_the_backend():
+    """Importing any module must not ask JAX for its backend or devices:
+    on a chip host that would claim the chip for whatever process merely
+    imports the package (one process per chip)."""
+    import os
+    import subprocess
+    import sys
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    out = subprocess.run([sys.executable, "-c", _IMPORT_ALL], env=env,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-3000:]
 
 
 def test_dryrun_input_specs():

@@ -40,12 +40,13 @@ def test_ckpt_resume_bitexact(tmp_path):
     from repro.optim import AdamW
     from repro.ckpt import CheckpointManager
     from repro import train_lib
+    from repro.launch.mesh import make_mesh
 
     cfg = dc.replace(smoke_config("codeqwen1.5-7b"), n_layers=2,
                      compute_dtype="float32")
     model = build_model(cfg)
     opt = AdamW(lr=1e-3, param_dtype="float32")
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     pcfg = ParallelConfig(tp=1, fsdp=False, batch_axes=("data",))
     step_fn = jax.jit(train_lib.make_train_step(model, opt, pcfg, mesh))
 
@@ -99,16 +100,16 @@ def test_hlo_cost_corrects_scan_tripcount():
 
 
 def test_hlo_cost_counts_collectives():
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.launch.hlo_cost import analyze_hlo
-    mesh = jax.make_mesh((1,), ("d",))
+    from repro.launch.mesh import make_mesh
+    mesh = make_mesh((1,), ("d",))
 
     def f(x):
         return jax.lax.psum(x, "d")
 
-    g = shard_map(f, mesh=mesh, in_specs=P("d"), out_specs=P(),
-                  check_rep=False)
+    g = jax.shard_map(f, mesh=mesh, in_specs=P("d"), out_specs=P(),
+                      check_vma=False)
     txt = jax.jit(g).lower(jnp.zeros((8, 128), jnp.float32)) \
         .compile().as_text()
     res = analyze_hlo(txt)
