@@ -73,8 +73,10 @@ def main(argv=None):
                     help="print the unified serving stats (and per-request "
                          "percentiles) after the run")
     ap.add_argument("--trace", default="",
-                    help="write a Chrome-trace JSON (chrome://tracing / "
-                         "Perfetto) of the run to this path")
+                    help="record a JAX profiler trace of the run under "
+                         "this directory: the program's spans and the "
+                         "device's ops on one clock (.xplane.pb, and a "
+                         "Perfetto trace.json.gz)")
     args = ap.parse_args(argv)
     from repro.launch.compile_cache import enable_compile_cache
     enable_compile_cache()
@@ -93,11 +95,8 @@ def main(argv=None):
     batch = batch_for_model(cfg, "prefill", 0, args.batch, args.prompt_len,
                             args.seed)
     batch = {k: jnp.asarray(v) for k, v in batch.items()}
-    writer = None
     if args.trace:
-        from repro.telemetry import TraceWriter, install_writer
-        writer = TraceWriter()
-        install_writer(writer)
+        jax.profiler.start_trace(args.trace, create_perfetto_trace=True)
     try:
         if impl == "paged" and args.replicas > 0:
             return _serve_cluster(model, params, batch, args)
@@ -105,11 +104,9 @@ def main(argv=None):
             return _serve_paged(model, params, batch, args)
         return _serve_dense(model, params, batch, args)
     finally:
-        if writer is not None:
-            from repro.telemetry import uninstall_writer
-            uninstall_writer()
-            writer.write(args.trace)
-            print(f"trace written to {args.trace}")
+        if args.trace:
+            jax.profiler.stop_trace()
+            print(f"trace written under {args.trace}")
 
 
 def init_params(model, seed: int):

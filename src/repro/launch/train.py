@@ -130,8 +130,10 @@ def main(argv=None):
                     default="1f1b")
     ap.add_argument("--pp-microbatches", type=int, default=4)
     ap.add_argument("--trace", default="",
-                    help="write a Chrome-trace JSON (chrome://tracing / "
-                         "Perfetto) of the run to this path")
+                    help="record a JAX profiler trace of the run under "
+                         "this directory: the program's spans and the "
+                         "device's ops on one clock (.xplane.pb, and a "
+                         "Perfetto trace.json.gz)")
     args = ap.parse_args(argv)
     from repro.launch.compile_cache import enable_compile_cache
     enable_compile_cache()
@@ -159,12 +161,6 @@ def main(argv=None):
                              and len(jax.devices()) % d == 0)
     mesh = build_mesh(args.parallel, args.pp_stages)
     plan = build_plan(args)
-
-    writer = None
-    if args.trace:
-        from repro.telemetry import TraceWriter, install_writer
-        writer = TraceWriter()
-        install_writer(writer)
 
     rng = jax.random.PRNGKey(args.seed)
     params = model.init(rng)
@@ -198,6 +194,8 @@ def main(argv=None):
                                    seed=args.seed, start_step=start_step)
     t0 = now()
     losses = []
+    if args.trace:
+        jax.profiler.start_trace(args.trace, create_perfetto_trace=True)
     try:
         for step, batch in loader:
             if step >= args.steps:
@@ -217,11 +215,9 @@ def main(argv=None):
         loader.stop()
         if manager:
             manager.wait()
-        if writer is not None:
-            from repro.telemetry import uninstall_writer
-            uninstall_writer()
-            writer.write(args.trace)
-            print(f"trace written to {args.trace}")
+        if args.trace:
+            jax.profiler.stop_trace()
+            print(f"trace written under {args.trace}")
 
     if manager:
         manager.save(state, min(args.steps, step), blocking=True)

@@ -89,6 +89,7 @@ def attn_defs(cfg):
     return defs
 
 
+@jax.named_scope("qkv")
 def project_qkv(cfg, params, x, positions=None, rope: bool = True):
     """x: (b, s, d) -> q (b,s,h,hd), k/v (b,s,kv,hd); RoPE applied."""
     cd = x.dtype
@@ -109,6 +110,7 @@ def project_qkv(cfg, params, x, positions=None, rope: bool = True):
     return q, k, v
 
 
+@jax.named_scope("out_proj")
 def out_proj(cfg, params, attn_out):
     """attn_out (b, s, h, hd) -> (b, s, d)."""
     y = jnp.einsum("bshk,hkd->bsd", attn_out,
@@ -206,6 +208,7 @@ def chunked_attention(q, k, v, *, causal: bool, q_chunk=1024, kv_chunk=1024,
     return jnp.concatenate(outs, axis=1) if nq > 1 else outs[0]
 
 
+@jax.named_scope("attention")
 def attention_core(cfg, q, k, v, *, causal=True, q_offset=0,
                    chunked_threshold=2048, impl=None):
     """Dispatch the training/prefill softmax core.
@@ -243,6 +246,7 @@ def attention_core(cfg, q, k, v, *, causal=True, q_offset=0,
 # ------------------------------- KV cache ----------------------------------
 
 
+@jax.named_scope("kv_write")
 def chunk_cache_update(cache_k, cache_v, k_new, v_new, positions):
     """Scatter a chunk of K/V into a dense cache at per-slot positions.
 
@@ -260,6 +264,7 @@ def chunk_cache_update(cache_k, cache_v, k_new, v_new, positions):
     return ck, cv
 
 
+@jax.named_scope("attention")
 def chunk_attention(cfg, q, cache_k, cache_v, positions, *, impl=None):
     """Chunk-of-T-tokens attention against a dense cache (T >= 1).
 
@@ -322,6 +327,7 @@ def paged_slot_index(block_tables, positions, block_size):
     return slots if positions.ndim == 2 else slots[:, 0]
 
 
+@jax.named_scope("kv_write")
 def paged_cache_update(k_pool, v_pool, k_new, v_new, slots,
                        k_scale=None, v_scale=None):
     """Scatter a chunk of new K/V into a block-paged pool.
@@ -360,6 +366,7 @@ def paged_cache_update(k_pool, v_pool, k_new, v_new, slots,
     return kf.reshape(k_pool.shape), vf.reshape(v_pool.shape)
 
 
+@jax.named_scope("attention")
 def paged_chunk_attn(cfg, q, k_pool, v_pool, block_tables, positions,
                      *, impl=None, k_scale=None, v_scale=None):
     """Chunk-of-T-tokens attention against a block-paged pool — the one

@@ -34,6 +34,7 @@ def norm_kernel_impl(cfg, x):
     return None
 
 
+@jax.named_scope("norm")
 def apply_norm(cfg, params, x, name="norm"):
     """Stats in fp32, scaling applied in the stream dtype.
 

@@ -81,6 +81,7 @@ class BaseLM:
     def param_shapes(self, dtype=None):
         return shape_tree(self.param_defs(), dtype)
 
+    @jax.named_scope("embed")
     def _embed(self, params, tokens):
         x = jnp.take(params["embed"], tokens, axis=0)
         return x.astype(self.compute_dtype)
@@ -101,6 +102,7 @@ class BaseLM:
         xl = jnp.take_along_axis(x, idx[:, None, None], axis=1)
         return self._logits(params, xl)[:, 0]
 
+    @jax.named_scope("lm_head")
     def _chunk_logits(self, params, x, positions, all_logits):
         """Chunk output head: (b, V) at each slot's last valid position
         by default, or — ``all_logits`` — the full (b, T, V) so callers
@@ -111,6 +113,7 @@ class BaseLM:
             return self._logits(params, x)
         return self._gather_logits(params, x, positions)
 
+    @jax.named_scope("lm_head")
     def _ce(self, params, x, labels, mask=None):
         logits = self._logits(params, x)
         return cross_entropy(logits, labels, mask)
@@ -260,13 +263,15 @@ class DecoderLM(BaseLM):
                 x, aux = self._moe_layer(lp, x, aux)
                 return (x, aux), None
             f = jax.checkpoint(body) if remat else body
-            (x, aux), _ = jax.lax.scan(f, (x, jnp.zeros((), jnp.float32)),
-                                       params["layers"])
+            with jax.named_scope("layers"):
+                (x, aux), _ = jax.lax.scan(
+                    f, (x, jnp.zeros((), jnp.float32)), params["layers"])
             return x, aux
         def body(carry, lp):
             return dense_layer(cfg, lp, carry, causal=True), None
         f = jax.checkpoint(body) if remat else body
-        x, _ = jax.lax.scan(f, x, params["layers"])
+        with jax.named_scope("layers"):
+            x, _ = jax.lax.scan(f, x, params["layers"])
         return x, jnp.zeros((), jnp.float32)
 
     def _inputs(self, params, batch):
@@ -333,17 +338,19 @@ class DecoderLM(BaseLM):
                                          group_size=self.moe_group,
                                          dropless=True)
                 return (x + y, aux + a), (ck, cv)
-            (x, _), (ck, cv) = jax.lax.scan(
-                body, (x, jnp.zeros((), jnp.float32)),
-                (params["layers"], state["k"], state["v"]))
+            with jax.named_scope("layers"):
+                (x, _), (ck, cv) = jax.lax.scan(
+                    body, (x, jnp.zeros((), jnp.float32)),
+                    (params["layers"], state["k"], state["v"]))
         else:
             def body(x, inp):
                 lp, ck, cv = inp
                 x, ck, cv = chunk_layer(cfg, lp, x, ck, cv, positions,
                                         fresh=fresh)
                 return x, (ck, cv)
-            x, (ck, cv) = jax.lax.scan(
-                body, x, (params["layers"], state["k"], state["v"]))
+            with jax.named_scope("layers"):
+                x, (ck, cv) = jax.lax.scan(
+                    body, x, (params["layers"], state["k"], state["v"]))
 
         logits = self._chunk_logits(params, x, positions, all_logits)
         return {**state, "k": ck, "v": cv}, logits
@@ -388,8 +395,9 @@ class DecoderLM(BaseLM):
                                          dropless=True)
                 ys = (kp, vp, ks, vs) if quant else (kp, vp)
                 return (x + y, aux + a), ys
-            (x, _), ys = jax.lax.scan(
-                body, (x, jnp.zeros((), jnp.float32)), xs)
+            with jax.named_scope("layers"):
+                (x, _), ys = jax.lax.scan(
+                    body, (x, jnp.zeros((), jnp.float32)), xs)
         else:
             def body(x, inp):
                 lp, kp, vp = inp[:3]
@@ -398,7 +406,8 @@ class DecoderLM(BaseLM):
                     cfg, lp, x, kp, vp, tables, positions, slots,
                     k_scale=ks, v_scale=vs)
                 return x, ((kp, vp, ks, vs) if quant else (kp, vp))
-            x, ys = jax.lax.scan(body, x, xs)
+            with jax.named_scope("layers"):
+                x, ys = jax.lax.scan(body, x, xs)
 
         logits = self._chunk_logits(params, x, positions, all_logits)
         lengths = jnp.max(positions, axis=1).astype(jnp.int32) + 1
@@ -550,9 +559,10 @@ class WhisperLM(BaseLM):
                                     fresh=fresh, cross_kv=(xk, xv))
             return x, (ck, cv)
 
-        x, (ck, cv) = jax.lax.scan(
-            body, x, (params["decoder"], state["k"], state["v"],
-                      state["xk"], state["xv"]))
+        with jax.named_scope("layers"):
+            x, (ck, cv) = jax.lax.scan(
+                body, x, (params["decoder"], state["k"], state["v"],
+                          state["xk"], state["xv"]))
         logits = self._chunk_logits(params, x, positions, all_logits)
         return {**state, "k": ck, "v": cv}, logits
 

@@ -66,6 +66,7 @@ def _shard_ge(x, g_axis_name, n_experts):
     return shard_act(x, *axes)
 
 
+@jax.named_scope("moe")
 def apply_moe(cfg, params, x, *, group_size=DEFAULT_GROUP, dropless=False):
     """x (b, s, d) -> (y (b, s, d), aux_loss).
 

@@ -35,6 +35,7 @@ def mlp_defs(cfg, d_ff=None, prefix=""):
     return defs
 
 
+@jax.named_scope("mlp")
 def apply_mlp(cfg, params, x, prefix=""):
     cd = x.dtype
     if is_gated(cfg.activation):
@@ -169,11 +170,13 @@ def scan_layers(fn, x, stacked, *, remat=True, extra_xs=None, extra_ys=False):
     if extra_xs is None and not extra_ys:
         def step(carry, lp):
             return body(carry, lp), None
-        x, _ = jax.lax.scan(step, x, stacked)
+        with jax.named_scope("layers"):
+            x, _ = jax.lax.scan(step, x, stacked)
         return x
 
     def step(carry, inp):
         return body(carry, *inp)
 
     xs = (stacked,) if extra_xs is None else (stacked, *extra_xs)
-    return jax.lax.scan(step, x, xs)
+    with jax.named_scope("layers"):
+        return jax.lax.scan(step, x, xs)

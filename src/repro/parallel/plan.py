@@ -140,8 +140,10 @@ def make_train_step(plan: ParallelPlan, model, optimizer, mesh, *,
     a state across steps must not).
 
     The returned callable is wrapped in a host-side ``train.step``
-    telemetry span *outside* the jit boundary (dispatch wall time, mode
-    attr) — every executor gets the same trace shape for free.  The
+    telemetry span *outside* the jit boundary: a profiler annotation
+    (``mode`` attr) around the step's dispatch, on the same clock as the
+    device ops it launches — every executor gets the same trace shape
+    for free.  The
     jitted step itself is its ``jitted`` attribute (AOT ``lower`` /
     ``compile``, e.g. to inspect the compiled program).
     """

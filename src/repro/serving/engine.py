@@ -369,7 +369,12 @@ class ServingEngine:
             req = self._queue[0]
             if req.arrival > self.step_count:
                 break
-            if not self._start(req):
+            with span("engine.admit", rid=req.rid,
+                      prompt_len=len(req.prompt)) as admit:
+                hits = self.cache.hits
+                started = self._start(req)
+                admit.set_metadata(prefix_hit=self.cache.hits > hits)
+            if not started:
                 break
             self._queue.pop(0)
 
@@ -423,8 +428,8 @@ class ServingEngine:
 
     def _advance_job(self, job: _PrefillJob) -> None:
         toks, pos = job.chunks[job.next]
-        # host wall time at the jit boundary: dispatch, not device sync —
-        # blocking here would serialize the prefill/decode interleave
+        # dispatch at the jit boundary, not device sync — blocking here
+        # would serialize the prefill/decode interleave
         with span("engine.prefill_chunk", rid=job.req.rid,
                   chunk=job.next, width=toks.shape[1]):
             job.state, job.logits = self._chunk(
@@ -438,10 +443,14 @@ class ServingEngine:
         req, cache = job.req, self.cache
         s = len(req.prompt)
         # (L, b=1, cap, kv, hd) -> (L, s, kv, hd)
-        cache.write_prompt(job.state["k"][:, 0, :s],
-                           job.state["v"][:, 0, :s], job.blocks)
+        with span("engine.write_prompt", rid=req.rid,
+                  blocks=len(job.blocks)):
+            cache.write_prompt(job.state["k"][:, 0, :s],
+                               job.state["v"][:, 0, :s], job.blocks)
         extras1 = {k: job.state[k] for k in self._extras_keys}
-        first = self._pick_token(req, job.logits[0], s)
+        # the host waits here for the prefill to finish on the device
+        with span("engine.first_token", rid=req.rid):
+            first = self._pick_token(req, job.logits[0], s)
         if self.share_prefixes and req.greedy:
             cache.register_prefix(req.prompt, job.blocks, s, first,
                                   extras=extras1 or None)
@@ -684,6 +693,13 @@ class ServingEngine:
         """Advance the in-flight prefill by one chunk, admit, decode one
         token for every running request, sample, retire.  Returns the
         number of tokens produced."""
+        with span("engine.step", step=self.step_count,
+                  queue=len(self._queue),
+                  active=sum(r is not None for r in self._slots),
+                  free_blocks=self.cache.num_free):
+            return self._step_once()
+
+    def _step_once(self) -> int:
         if self._job is not None:
             self._advance_job(self._job)
             if self._job.finished:
@@ -707,47 +723,52 @@ class ServingEngine:
             return 0
         if self.drafter is not None:
             return self._spec_step()
-        # Walk slots (not a snapshot): _evict_for_space can clear any
-        # slot mid-loop, and an evicted request must not be handed a
-        # block it would never free.
-        for slot in range(self.max_slots):
-            req = self._slots[slot]
-            if req is None:
-                continue
-            while self._slots[slot] is req and not self._ensure_block(req):
-                if not self._evict_for_space(req):
-                    raise RuntimeError(
-                        f"KV pool exhausted: request {req.rid} needs a "
-                        f"block and nothing is evictable")
-        active = [r for r in self._slots if r is not None]
+        with span("engine.prepare_tick") as prep:
+            evictions = self.evictions
+            # Walk slots (not a snapshot): _evict_for_space can clear any
+            # slot mid-loop, and an evicted request must not be handed a
+            # block it would never free.
+            for slot in range(self.max_slots):
+                req = self._slots[slot]
+                if req is None:
+                    continue
+                while (self._slots[slot] is req
+                       and not self._ensure_block(req)):
+                    if not self._evict_for_space(req):
+                        raise RuntimeError(
+                            f"KV pool exhausted: request {req.rid} needs "
+                            f"a block and nothing is evictable")
+            active = [r for r in self._slots if r is not None]
 
-        width = self._bucket(max(len(r.blocks) for r in active))
-        tables = np.zeros((self.max_slots, width), np.int32)
-        lengths = np.zeros(self.max_slots, np.int32)
-        tokens = np.zeros(self.max_slots, np.int32)
-        temps = np.zeros(self.max_slots, np.float32)
-        topks = np.zeros(self.max_slots, np.int32)
-        keys = np.zeros((self.max_slots, 2), np.uint32)
-        for r in active:
-            tables[r.slot, :len(r.blocks)] = r.blocks
-            lengths[r.slot] = r.length
-            tokens[r.slot] = r.tokens[-1]
-            temps[r.slot] = r.temperature
-            topks[r.slot] = r.top_k
-            if not r.greedy:
-                keys[r.slot] = self._base_key(r)
+            width = self._bucket(max(len(r.blocks) for r in active))
+            tables = np.zeros((self.max_slots, width), np.int32)
+            lengths = np.zeros(self.max_slots, np.int32)
+            tokens = np.zeros(self.max_slots, np.int32)
+            temps = np.zeros(self.max_slots, np.float32)
+            topks = np.zeros(self.max_slots, np.int32)
+            keys = np.zeros((self.max_slots, 2), np.uint32)
+            for r in active:
+                tables[r.slot, :len(r.blocks)] = r.blocks
+                lengths[r.slot] = r.length
+                tokens[r.slot] = r.tokens[-1]
+                temps[r.slot] = r.temperature
+                topks[r.slot] = r.top_k
+                if not r.greedy:
+                    keys[r.slot] = self._base_key(r)
 
-        # the paged SeqState: block tables, per-slot lengths, and the
-        # per-slot PRNG keys ride inside the state pytree
-        state = {"k": self.cache.k, "v": self.cache.v,
-                 "block_tables": jnp.asarray(tables),
-                 "lengths": jnp.asarray(lengths),
-                 "rng": jnp.asarray(keys), **self._extras}
-        if self.cache.quantized:
-            state["k_scale"] = self.cache.k_scale
-            state["v_scale"] = self.cache.v_scale
+            # the paged SeqState: block tables, per-slot lengths, and the
+            # per-slot PRNG keys ride inside the state pytree
+            state = {"k": self.cache.k, "v": self.cache.v,
+                     "block_tables": jnp.asarray(tables),
+                     "lengths": jnp.asarray(lengths),
+                     "rng": jnp.asarray(keys), **self._extras}
+            if self.cache.quantized:
+                state["k_scale"] = self.cache.k_scale
+                state["v_scale"] = self.cache.v_scale
+            prep.set_metadata(width=width,
+                              evicted=self.evictions - evictions)
         with span("engine.decode_tick", step=self.step_count,
-                  active=len(active)):
+                  active=len(active), width=width):
             state, logits = self._step(self.params, state,
                                        jnp.asarray(tokens)[:, None],
                                        jnp.asarray(lengths)[:, None])
@@ -758,37 +779,44 @@ class ServingEngine:
         self._extras = {k: state[k] for k in self._extras_keys}
         # pick on device: ship (max_slots,) int32 to host, not the
         # (max_slots, vocab) logits; an all-greedy step (the default)
-        # skips the full-vocab sort the top-k sampler needs
-        if all(r.greedy for r in active):
-            next_toks = np.asarray(jnp.argmax(logits, axis=-1), np.int32)
-        else:
-            # token about to be sampled lands at position length + 1
-            next_toks = np.asarray(self._sample(
-                logits, state["rng"], jnp.asarray(lengths) + 1,
-                jnp.asarray(temps), jnp.asarray(topks)), np.int32)
+        # skips the full-vocab sort the top-k sampler needs.  The host
+        # waits here for the tick to finish on the device.
+        with span("engine.fetch_tokens", active=len(active)):
+            if all(r.greedy for r in active):
+                next_toks = np.asarray(jnp.argmax(logits, axis=-1),
+                                       np.int32)
+            else:
+                # token about to be sampled lands at position length + 1
+                next_toks = np.asarray(self._sample(
+                    logits, state["rng"], jnp.asarray(lengths) + 1,
+                    jnp.asarray(temps), jnp.asarray(topks)), np.int32)
 
-        produced = 0
-        tnow = now()      # one clock read for the whole batched tick
-        for r in active:
-            r.length += 1
-            r.tokens.append(int(next_toks[r.slot]))
-            produced += 1
-            if r.t_last is not None:
-                # per-token TPOT: interval since this request's previous
-                # token (includes eviction-replay gaps — what the user saw)
-                dt = tnow - r.t_last
-                self._h_tpot.record(dt)
-                r.tpot_sum += dt
-                r.tpot_n += 1
-            r.t_last = tnow
-            if r.done:
-                self._slots[r.slot] = None
-                self.cache.free(r.blocks)
-                r.slot, r.status = -1, "done"
-                # telemetry is captured *here*, at completion — run()
-                # clears _done, so drain-time recording would lose it
-                self._record_request(r)
-                self._done[r.rid] = r
+        with span("engine.retire") as ret:
+            produced = finished = 0
+            tnow = now()      # one clock read for the whole batched tick
+            for r in active:
+                r.length += 1
+                r.tokens.append(int(next_toks[r.slot]))
+                produced += 1
+                if r.t_last is not None:
+                    # per-token TPOT: interval since this request's
+                    # previous token (includes eviction-replay gaps —
+                    # what the user saw)
+                    dt = tnow - r.t_last
+                    self._h_tpot.record(dt)
+                    r.tpot_sum += dt
+                    r.tpot_n += 1
+                r.t_last = tnow
+                if r.done:
+                    self._slots[r.slot] = None
+                    self.cache.free(r.blocks)
+                    r.slot, r.status = -1, "done"
+                    # telemetry is captured *here*, at completion — run()
+                    # clears _done, so drain-time recording would lose it
+                    self._record_request(r)
+                    self._done[r.rid] = r
+                    finished += 1
+            ret.set_metadata(finished=finished)
         self.step_count += 1
         return produced
 

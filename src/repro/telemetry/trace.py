@@ -1,27 +1,32 @@
-"""Host-side spans, Chrome-trace export, and the structured event log.
+"""Host-side spans on the profiler's clock, and the structured event log.
 
 ``span("name", **attrs)`` wraps a host-side region — a prefill chunk, a
-decode tick, a train step, a checkpoint write — at jit *boundaries*:
-spans time dispatch-to-dispatch wall clock and never run inside a traced
-function, so instrumentation can't change what XLA compiles (the
+decode tick, a train step, a checkpoint write — as a
+``jax.profiler.TraceAnnotation``: while a profiler trace is recording
+(``jax.profiler.start_trace``, or a launcher's ``--trace DIR``), the
+region lands on the host plane of that trace with its attributes as
+metadata, on the same clock as the device's operations.  While nothing
+records, a span costs the annotation's construction and an inactive
+check.  Spans wrap jit *boundaries* and never run inside a traced
+function, so instrumentation cannot change what XLA compiles (the
 zero-extra-traces guard in tests/test_telemetry.py pins this).
 
-Three sinks, all optional:
+A count known only when the region ends goes in through the span's
+``set_metadata(**attrs)``::
 
-  * the ``"default"`` registry gets a ``span.<name>`` latency histogram
-    per span name (always on while telemetry is enabled);
-  * an installed :class:`TraceWriter` additionally records a Chrome
-    trace-event-format "X" (complete) event per span — ``write(path)``
-    emits JSON that loads directly in ``chrome://tracing`` / Perfetto;
-  * :class:`EventLog` carries the *discrete* event stream (failure /
-    straggler / rescale / ckpt — the paper's Table-6 taxonomy) as JSONL
-    and mirrors each record into the TraceWriter as an instant event.
+    with span("engine.prepare_tick") as s:
+        ...
+        s.set_metadata(width=width, evicted=n)
+
+:class:`EventLog` carries the *discrete* event stream (failure /
+straggler / rescale / ckpt — the paper's Table-6 taxonomy) as JSONL and
+mirrors each record into the trace as a zero-length ``event.<kind>``
+annotation carrying its fields.
 
 ``set_enabled(False)`` (or env ``REPRO_TELEMETRY=0``) swaps ``span``
-for a shared no-op object: no clock reads, no allocation beyond the
-call itself.  Code that needs a *measurement* (validator bandwidth,
-straggler detection) must therefore use :func:`now` directly rather
-than a span's duration — spans are observability, not control flow.
+for a shared no-op object.  Code that needs a *measurement* (validator
+bandwidth, straggler detection) uses :func:`now` directly: spans are
+observability, not control flow.
 
 This module (with ``registry.py``) is the one place in ``src/`` allowed
 to call ``time.perf_counter`` — the CI guard lane greps everything else.
@@ -33,13 +38,12 @@ import os
 import threading
 import time
 
-from repro.telemetry.registry import get_registry
-
 now = time.perf_counter
 
 _enabled = os.environ.get("REPRO_TELEMETRY", "1") != "0"
-_writer: "TraceWriter | None" = None
-_origin = now()          # process-relative ts origin for trace events
+# jax.profiler.TraceAnnotation, imported at the first span: importing
+# this module must not import JAX (nor query a backend)
+_annotation = None
 
 
 def enabled() -> bool:
@@ -51,54 +55,10 @@ def set_enabled(on: bool) -> None:
     _enabled = bool(on)
 
 
-def install_writer(writer: "TraceWriter") -> None:
-    global _writer
-    _writer = writer
-
-
-def uninstall_writer() -> None:
-    global _writer
-    _writer = None
-
-
-def get_writer() -> "TraceWriter | None":
-    return _writer
-
-
-class Span:
-    """One timed host-side region; re-entrant via nesting, not reuse."""
-
-    __slots__ = ("name", "attrs", "t0", "duration_s")
-
-    def __init__(self, name: str, attrs: dict | None):
-        self.name = name
-        self.attrs = attrs
-        self.t0 = 0.0
-        self.duration_s = 0.0
-
-    def __enter__(self) -> "Span":
-        self.t0 = now()
-        return self
-
-    def __exit__(self, exc_type, exc, tb) -> None:
-        t1 = now()
-        self.duration_s = t1 - self.t0
-        get_registry().histogram(f"span.{self.name}").record(self.duration_s)
-        w = _writer
-        if w is not None:
-            w.add_complete(self.name, self.t0, t1, self.attrs,
-                           error=exc_type.__name__ if exc_type else None)
-        return None          # never swallow the exception
-
-
 class _NullSpan:
-    """Shared no-op span when telemetry is disabled: no clock reads."""
+    """Shared no-op span when telemetry is disabled."""
 
     __slots__ = ()
-    name = ""
-    attrs = None
-    t0 = 0.0
-    duration_s = 0.0
 
     def __enter__(self):
         return self
@@ -106,77 +66,23 @@ class _NullSpan:
     def __exit__(self, exc_type, exc, tb):
         return None
 
+    def set_metadata(self, **attrs) -> None:
+        return None
+
 
 _NULL_SPAN = _NullSpan()
 
 
-def span(name: str, **attrs):
-    """``with span("engine.decode_tick", active=4): ...``"""
+def span(name: str, /, **attrs):
+    """``with span("engine.decode_tick", active=4): ...`` — a profiler
+    annotation named ``name`` with ``attrs`` as its metadata."""
+    global _annotation
     if not _enabled:
         return _NULL_SPAN
-    return Span(name, attrs or None)
-
-
-class TraceWriter:
-    """Chrome trace-event-format (catapult) collector.
-
-    Events use the JSON-object-array form ``{"traceEvents": [...]}`` with
-    microsecond ``ts``/``dur`` relative to the writer's construction —
-    the schema ``chrome://tracing`` and Perfetto load natively.  Spans
-    land as ``ph: "X"`` (complete) events; :class:`EventLog` records as
-    ``ph: "i"`` (instant, thread scope).  Thread identity maps to small
-    stable ``tid`` ints in first-seen order.
-    """
-
-    def __init__(self, process_name: str = "repro"):
-        self.process_name = process_name
-        self.events: list[dict] = []
-        self._pid = os.getpid()
-        self._tids: dict[int, int] = {}
-        self._lock = threading.Lock()
-        self._t0 = now()
-
-    def _tid(self) -> int:
-        ident = threading.get_ident()
-        t = self._tids.get(ident)
-        if t is None:
-            t = self._tids[ident] = len(self._tids)
-        return t
-
-    def _ts(self, t: float) -> float:
-        return (t - self._t0) * 1e6
-
-    def add_complete(self, name: str, t0: float, t1: float,
-                     attrs: dict | None = None, error: str | None = None):
-        ev = {"name": name, "ph": "X", "ts": self._ts(t0),
-              "dur": (t1 - t0) * 1e6, "pid": self._pid, "tid": self._tid(),
-              "cat": "span"}
-        if attrs or error:
-            ev["args"] = dict(attrs or {})
-            if error:
-                ev["args"]["error"] = error
-        with self._lock:
-            self.events.append(ev)
-
-    def add_instant(self, name: str, attrs: dict | None = None):
-        ev = {"name": name, "ph": "i", "ts": self._ts(now()), "s": "t",
-              "pid": self._pid, "tid": self._tid(), "cat": "event"}
-        if attrs:
-            ev["args"] = dict(attrs)
-        with self._lock:
-            self.events.append(ev)
-
-    def to_json(self) -> dict:
-        meta = [{"name": "process_name", "ph": "M", "pid": self._pid,
-                 "tid": 0, "args": {"name": self.process_name}}]
-        with self._lock:
-            return {"traceEvents": meta + list(self.events),
-                    "displayTimeUnit": "ms"}
-
-    def write(self, path: str) -> str:
-        with open(path, "w") as f:
-            json.dump(self.to_json(), f, default=str)
-        return path
+    if _annotation is None:
+        from jax.profiler import TraceAnnotation
+        _annotation = TraceAnnotation
+    return _annotation(name, **attrs)
 
 
 class EventLog:
@@ -199,10 +105,8 @@ class EventLog:
         rec = {"kind": kind, "t": now() - self._t0, **fields}
         with self._lock:
             self.events.append(rec)
-        if _enabled:
-            w = _writer
-            if w is not None:
-                w.add_instant(kind, fields)
+        with span("event." + kind, **fields):
+            pass
         return rec
 
     def write(self, path: str) -> str:

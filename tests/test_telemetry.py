@@ -1,11 +1,17 @@
-"""Telemetry layer (DESIGN.md §10): registry exactness, span/trace
-schema, event-log routing, per-request engine percentiles, and the
-zero-extra-jit-traces + one-clock guards."""
+"""Telemetry layer (DESIGN.md §10): registry exactness, spans as
+profiler annotations (read back from a recorded CPU trace), event-log
+routing, the engine's span tree, the model's named scopes, per-request
+engine percentiles, and the zero-extra-jit-traces + one-clock guards."""
 import dataclasses as dc
+import glob
+import gzip
 import json
 import pathlib
+import re
+import warnings
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -14,16 +20,42 @@ from repro.data.synthetic import batch_for_model
 from repro.models import build_model
 from repro.serving import ServingEngine
 from repro.telemetry import (Counter, EventLog, Gauge, Histogram, Registry,
-                             TraceWriter, get_writer, install_writer,
-                             set_enabled, span, uninstall_writer)
+                             set_enabled, span)
 
 
 @pytest.fixture(autouse=True)
 def _clean_telemetry():
-    """Spans/writers are process globals — leave them as found."""
+    """Enablement is a process global — leave it as found."""
     yield
-    uninstall_writer()
     set_enabled(True)
+
+
+def _host_events(directory) -> list:
+    """[(name, start_ns, end_ns, {stat: value})] of the host plane of the
+    profile recorded under ``directory``, by start."""
+    from jax.profiler import ProfileData
+    paths = glob.glob(str(pathlib.Path(directory) / "**" / "*.xplane.pb"),
+                      recursive=True)
+    assert len(paths) == 1, paths
+    with warnings.catch_warnings():
+        # jaxlib builds the stats view's type on first use, and warns
+        warnings.simplefilter("ignore", DeprecationWarning)
+        out = [(e.name, e.start_ns, e.end_ns, dict(e.stats))
+               for plane in ProfileData.from_file(paths[0]).planes
+               if plane.name.startswith("/host:")
+               for line in plane.lines for e in line.events]
+    return sorted(out, key=lambda e: (e[1], -e[2]))
+
+
+def _recorded(tmp_path, fn) -> list:
+    """Run ``fn`` under a CPU profiler trace; its host events."""
+    with jax.profiler.trace(str(tmp_path)):
+        fn()
+    return _host_events(tmp_path)
+
+
+def _inside(inner, outer) -> bool:
+    return outer[1] <= inner[1] and inner[2] <= outer[2]
 
 
 def _build(arch="codeqwen1.5-7b", **over):
@@ -99,61 +131,70 @@ def test_registry_singletons_and_in_place_reset():
 # ------------------------------ spans + traces ------------------------------
 
 
-def test_span_nesting_and_exception_safety():
-    w = TraceWriter()
-    install_writer(w)
-    with span("outer", step=1):
-        with span("inner"):
-            pass
-    with pytest.raises(ValueError):
-        with span("boom"):
-            raise ValueError("x")
-    names = [e["name"] for e in w.events]
-    assert names == ["inner", "outer", "boom"]   # exit order
-    inner, outer, boom = w.events
-    # nesting: the inner interval is contained in the outer one
-    assert outer["ts"] <= inner["ts"]
-    assert inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"] + 1e-3
-    assert outer["args"] == {"step": 1}
-    assert boom["args"]["error"] == "ValueError"
-    # span histograms land in the default registry
-    assert Registry.get().histogram("span.outer").count >= 1
+def test_span_nesting_and_exception_safety(tmp_path):
+    def work():
+        with span("outer", step=1, mode="gspmd"):
+            with span("inner") as s:
+                s.set_metadata(width=8)
+        with pytest.raises(ValueError):
+            with span("boom"):
+                raise ValueError("x")
+
+    evs = {e[0]: e for e in _recorded(tmp_path, work)
+           if e[0] in ("outer", "inner", "boom")}
+    assert set(evs) == {"outer", "inner", "boom"}    # boom still closed
+    outer, inner, boom = evs["outer"], evs["inner"], evs["boom"]
+    assert _inside(inner, outer) and boom[1] >= outer[2]
+    assert outer[3] == {"step": 1, "mode": "gspmd"}
+    assert inner[3] == {"width": 8}                  # set at the end
 
 
-def test_disabled_spans_are_shared_null_and_writer_silent():
-    w = TraceWriter()
-    install_writer(w)
-    set_enabled(False)
-    s1, s2 = span("a"), span("b", x=1)
-    assert s1 is s2                       # one shared null object
-    with s1:
-        pass
-    assert w.events == []
-    set_enabled(True)
+def test_disabled_spans_are_shared_null_and_writer_silent(tmp_path):
+    def work():
+        set_enabled(False)
+        s1, s2 = span("a"), span("b", x=1)
+        assert s1 is s2                   # one shared null object
+        with s1 as s:
+            s.set_metadata(y=2)
+        EventLog().emit("ckpt", step=1)
+        set_enabled(True)
+
+    names = {e[0] for e in _recorded(tmp_path, work)}
+    assert not names & {"a", "b", "event.ckpt"}
     assert span("a") is not span("a")
 
 
 def test_chrome_trace_schema_roundtrip(tmp_path):
-    w = TraceWriter()
-    install_writer(w)
+    """An EventLog record lands in the trace as a zero-length
+    ``event.<kind>`` annotation inside the enclosing span, in the
+    .xplane.pb and in the Chrome-trace JSON the profiler converts it
+    to."""
     log = EventLog()
-    with span("phase.work", k=2):
-        log.emit("failure", node=3, cls="sw_xid43")
-    path = w.write(str(tmp_path / "trace.json"))
-    doc = json.loads(pathlib.Path(path).read_text())
-    assert set(doc) == {"traceEvents", "displayTimeUnit"}
-    evs = doc["traceEvents"]
-    assert evs[0]["ph"] == "M" and evs[0]["name"] == "process_name"
-    xs = [e for e in evs if e["ph"] == "X"]
-    inst = [e for e in evs if e["ph"] == "i"]
-    assert len(xs) == 1 and len(inst) == 1
-    for e in xs:
+
+    def work():
+        with span("phase.work", k=2):
+            log.emit("failure", node=3, cls="sw_xid43")
+
+    evs = {e[0]: e for e in _recorded(tmp_path, work)}
+    work_ev, fail = evs["phase.work"], evs["event.failure"]
+    assert _inside(fail, work_ev) and fail[3] == {"node": 3,
+                                                  "cls": "sw_xid43"}
+    assert log.events[0]["kind"] == "failure"
+
+    with jax.profiler.trace(str(tmp_path / "json"),
+                            create_perfetto_trace=True):
+        work()
+    [path] = glob.glob(str(tmp_path / "json" / "**" /
+                           "perfetto_trace.json.gz"), recursive=True)
+    doc = json.loads(gzip.open(path).read())
+    xs = {e["name"]: e for e in doc["traceEvents"]
+          if e.get("ph") == "X"
+          and e["name"] in ("phase.work", "event.failure")}
+    assert set(xs) == {"phase.work", "event.failure"}
+    for e in xs.values():
         assert {"name", "ph", "ts", "dur", "pid", "tid"} <= set(e)
-        assert e["dur"] >= 0 and e["ts"] >= 0
-    assert inst[0]["name"] == "failure" and inst[0]["s"] == "t"
-    # the instant falls inside the enclosing span
-    x = xs[0]
-    assert x["ts"] <= inst[0]["ts"] <= x["ts"] + x["dur"]
+    x, i = xs["phase.work"], xs["event.failure"]
+    assert x["ts"] <= i["ts"] <= x["ts"] + x["dur"]
 
 
 def test_event_log_jsonl_roundtrip(tmp_path):
@@ -217,15 +258,14 @@ def test_engine_request_metrics_staggered_arrivals():
     assert eng.stats["requests_completed"] == len(rids)
 
 
-def test_engine_zero_extra_jit_traces_from_telemetry():
-    """Telemetry fully on (spans + writer) must not change what gets
+def test_engine_zero_extra_jit_traces_from_telemetry(tmp_path):
+    """Spans recording into a profiler trace must not change what gets
     compiled: trace counters are incremented at jit trace time."""
     cfg, model, params = _build()
 
-    install_writer(TraceWriter())
-    eng_on, _, _ = _run_staggered(model, cfg, params)
+    with jax.profiler.trace(str(tmp_path)):
+        eng_on, _, _ = _run_staggered(model, cfg, params)
     on = (eng_on.prefill_traces, eng_on.decode_traces)
-    uninstall_writer()
 
     set_enabled(False)
     eng_off, _, _ = _run_staggered(model, cfg, params)
@@ -233,7 +273,71 @@ def test_engine_zero_extra_jit_traces_from_telemetry():
     set_enabled(True)
 
     assert on == off
-    assert get_writer() is None
+    assert "engine.decode_tick" in {e[0] for e in _host_events(tmp_path)}
+
+
+ENGINE_TREE = ["engine.step", "engine.admit", "engine.prefill_chunk",
+               "engine.write_prompt", "engine.first_token",
+               "engine.prepare_tick", "engine.decode_tick",
+               "engine.fetch_tokens", "engine.retire"]
+
+
+def test_engine_step_span_tree(tmp_path):
+    """One ``step()`` that admits a request and decodes: the spans of
+    DESIGN.md §10 in order, nested under ``engine.step``, the
+    request's spans carrying its rid, and the counters at their
+    boundaries."""
+    cfg, model, params = _build()
+    eng = ServingEngine(model, params, n_blocks=24, block_size=16,
+                        max_slots=2)
+    prompt = np.asarray(batch_for_model(cfg, "prefill", 0, 1, 18)["tokens"],
+                        np.int32)[0]
+    rid = eng.submit(prompt, 4)
+
+    evs = [e for e in _recorded(tmp_path, eng.step)
+           if e[0].startswith("engine.")]
+    assert [e[0] for e in evs] == ENGINE_TREE
+    ev = dict((e[0], e) for e in evs)
+    step = ev["engine.step"]
+    assert all(_inside(e, step) for e in evs)
+    for name in ENGINE_TREE[2:5]:
+        assert _inside(ev[name], ev["engine.admit"])
+    for name in ENGINE_TREE[1:5]:
+        assert ev[name][3]["rid"] == rid
+    assert step[3] == {"step": 0, "queue": 1, "active": 0,
+                       "free_blocks": 23}
+    assert ev["engine.admit"][3]["prompt_len"] == 18
+    assert ev["engine.admit"][3]["prefix_hit"] == 0
+    assert ev["engine.write_prompt"][3]["blocks"] == 2
+    assert ev["engine.prepare_tick"][3] == {"width": 2, "evicted": 0}
+    assert ev["engine.decode_tick"][3] == {"step": 0, "active": 1,
+                                           "width": 2}
+    assert ev["engine.fetch_tokens"][3] == {"active": 1}
+    assert ev["engine.retire"][3] == {"finished": 0}
+
+
+MODEL_SCOPES = ("layers", "embed", "norm", "qkv", "kv_write", "attention",
+                "out_proj", "mlp", "lm_head")
+
+
+def test_decode_program_op_metadata_holds_model_scopes():
+    """The compiled decode program names the layer that produced each
+    op: its op_name metadata (the TPU trace's ``tf_op``) carries the
+    model's named scopes."""
+    cfg, model, params = _build()
+    eng = ServingEngine(model, params, n_blocks=24, block_size=16,
+                        max_slots=2)
+    state = {"k": eng.cache.k, "v": eng.cache.v,
+             "block_tables": jnp.zeros((2, 2), jnp.int32),
+             "lengths": jnp.zeros(2, jnp.int32),
+             "rng": jnp.zeros((2, 2), jnp.uint32)}
+    toks = jnp.zeros((2, 1), jnp.int32)
+    text = eng._step.lower(params, state, toks, toks).compile().as_text()
+    paths = re.findall(r'op_name="([^"]*)"', text)
+    parts = {p for path in paths for p in path.split("/")}
+    assert set(MODEL_SCOPES) <= parts
+    assert any(p.startswith("jit(_decode_fn)/layers/") and "/qkv/" in p
+               for p in paths)
 
 
 # ------------------------------ FT runner routing ---------------------------
@@ -281,20 +385,26 @@ def test_ftrunner_routes_every_event_through_one_log(tmp_path):
 
 
 def test_serve_launcher_trace_flag_writes_chrome_json(tmp_path):
+    """``--trace DIR`` records a profiler trace of the run: the engine's
+    spans on the host plane, and a Perfetto (Chrome-trace JSON) copy."""
     from repro.launch import serve
 
-    out = tmp_path / "serve_trace.json"
+    out = tmp_path / "serve_trace"
     serve.main(["--arch", "codeqwen1.5-7b", "--smoke",
                 "--decode-impl", "paged", "--batch", "2",
                 "--prompt-len", "12", "--gen", "4",
                 "--trace", str(out)])
-    doc = json.loads(out.read_text())
-    evs = doc["traceEvents"]
-    assert evs[0]["ph"] == "M"
-    xs = [e for e in evs if e["ph"] == "X"]
+    names = {e[0] for e in _host_events(out)}
+    assert {"engine.step", "engine.decode_tick",
+            "engine.prefill_chunk"} <= names
+    [path] = glob.glob(str(out / "**" / "perfetto_trace.json.gz"),
+                       recursive=True)
+    xs = [e for e in json.loads(gzip.open(path).read())["traceEvents"]
+          if e.get("ph") == "X"]
     assert any(e["name"] == "engine.decode_tick" for e in xs)
-    assert any(e["name"] == "engine.prefill_chunk" for e in xs)
     for e in xs:
         assert {"name", "ph", "ts", "dur", "pid", "tid"} <= set(e)
         assert e["dur"] >= 0
-    assert get_writer() is None               # launcher uninstalls
+    # the launcher stopped its trace: a new one can start
+    with jax.profiler.trace(str(tmp_path / "again")):
+        pass
