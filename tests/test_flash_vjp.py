@@ -4,7 +4,8 @@ Pallas kernels run in interpret mode; the oracle is jax autodiff through
 ``attention_ref``.  Covers causal/non-causal, GQA group sizes > 1,
 skv > sq (q_offset), and non-multiple-of-block sequence lengths, plus a
 regression test that the forward's saved logsumexp residual matches the
-reference softmax normalizer.
+reference softmax normalizer.  Cases with blocks ``None`` run at the
+blocks ``ops.block_sizes`` picks, where dead causal blocks are skipped.
 """
 import types
 
@@ -23,19 +24,25 @@ def _rand(shape):
 
 
 GRAD_CASES = [
-    # b, h, kvh, sq, skv, d, causal
-    (1, 2, 2, 128, 128, 32, True),      # MHA, causal, multi-block
-    (1, 4, 2, 128, 128, 32, False),     # GQA group 2, non-causal
-    (2, 4, 1, 128, 192, 32, True),      # GQA group 4, skv > sq (q_offset)
-    (1, 2, 2, 160, 160, 32, True),      # non-multiple-of-block seq
-    (1, 2, 1, 100, 100, 32, False),     # seq < block, unaligned
+    # b, h, kvh, sq, skv, d, causal, blocks (None: the rule's)
+    (1, 2, 2, 128, 128, 32, True, 64),      # MHA, causal, multi-block
+    (1, 4, 2, 128, 128, 32, False, 64),     # GQA group 2, non-causal
+    (2, 4, 1, 128, 192, 32, True, 64),      # GQA group 4, skv > sq (q_offset)
+    (1, 2, 2, 160, 160, 32, True, 64),      # non-multiple-of-block seq
+    (1, 2, 1, 100, 100, 32, False, 64),     # seq < block, unaligned
+    (1, 2, 2, 1024, 1024, 32, True, None),  # 2x2 of 512: one dead block
+    (1, 2, 1, 1024, 1278, 32, True, None),  # q_offset 254: 512 x 256 blocks
+    (1, 1, 1, 1024, 1281, 32, True, None),  # q_offset 257: 512 x 128 blocks
+    (1, 6, 2, 640, 640, 128, True, None),   # GQA group 3, d 128: 5x5 of 128
+    (1, 2, 2, 1500, 1500, 32, True, None),  # unaligned: kv_len 1500 of 1536
+    (1, 2, 2, 200, 1500, 32, False, None),  # cross-attention at 1500
 ]
 
 
 @pytest.mark.parametrize("case", GRAD_CASES)
 def test_flash_attention_grads_match_ref(case):
     from repro.kernels.flash_attention import attention_ref, flash_attention
-    b, h, kvh, sq, skv, d, causal = case
+    b, h, kvh, sq, skv, d, causal, blocks = case
     q = _rand((b, h, sq, d))
     k = _rand((b, kvh, skv, d))
     v = _rand((b, kvh, skv, d))
@@ -43,7 +50,7 @@ def test_flash_attention_grads_match_ref(case):
 
     def loss_kernel(q, k, v):
         out = flash_attention(q, k, v, causal=causal, impl="interpret",
-                              bq=64, bk=64)
+                              bq=blocks, bk=blocks)
         return jnp.sum(out * ct)
 
     def loss_ref(q, k, v):
@@ -58,16 +65,25 @@ def test_flash_attention_grads_match_ref(case):
 
 @pytest.mark.parametrize("case", GRAD_CASES)
 def test_flash_attention_forward_matches_ref(case):
-    """The padded/custom_vjp forward path (not just the raw kernel)."""
-    from repro.kernels.flash_attention import attention_ref, flash_attention
-    b, h, kvh, sq, skv, d, causal = case
+    """The padded/custom_vjp forward path (not just the raw kernel), and
+    the logsumexp residual it saves for the backward."""
+    from repro.kernels.flash_attention import (attention_ref,
+                                               attention_ref_lse,
+                                               flash_attention)
+    from repro.kernels.flash_attention.ops import _fwd
+    b, h, kvh, sq, skv, d, causal, blocks = case
     q = _rand((b, h, sq, d))
     k = _rand((b, kvh, skv, d))
     v = _rand((b, kvh, skv, d))
     out = flash_attention(q, k, v, causal=causal, impl="interpret",
-                          bq=64, bk=64)
+                          bq=blocks, bk=blocks)
     ref = attention_ref(q, k, v, causal=causal)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+    _, lse = _fwd(q, k, v, causal, skv - sq, True, blocks, blocks, True)
+    np.testing.assert_allclose(np.asarray(lse),
+                               np.asarray(attention_ref_lse(q, k,
+                                                            causal=causal)),
+                               atol=1e-4)
 
 
 @pytest.mark.parametrize("causal", [True, False])

@@ -66,6 +66,8 @@ def test_paged_chunk_attention_phi4(one_chip, T, kv_dtype):
 @pytest.mark.parametrize("b,h,kvh,s,d", [
     (8, 16, 16, 1024, 64),      # gpt2-medium
     (2, 24, 8, 2048, 128),      # phi4-mini (GQA 24/8)
+    (1, 24, 8, 64, 128),        # phi4-mini prefill: the rule's least blocks
+    (1, 24, 8, 512, 128),       # phi4-mini prefill: the median prompt
 ])
 def test_flash_attention_fwd_bwd(one_chip, b, h, kvh, s, d):
     from repro.kernels.flash_attention import flash_attention
